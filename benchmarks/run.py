@@ -23,14 +23,16 @@ def main(argv=None) -> None:
 
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for name, fn in paper_tables.ALL_TABLES:
         if only and name not in only:
             continue
         t = time.time()
         try:
             rows = fn()
-        except Exception as exc:  # pragma: no cover - report, don't die
+        except Exception as exc:  # report the section, run the rest
             print(f"{name},0,ERROR {type(exc).__name__}: {exc}")
+            failed.append(name)
             continue
         for row in rows:
             print(row)
@@ -40,13 +42,18 @@ def main(argv=None) -> None:
     if not args.skip_tpu and (only is None or "tpu" in only):
         try:
             from benchmarks import tpu_sectored
-            for row in tpu_sectored.run_all():
+            rows = tpu_sectored.run_all()
+        except Exception as exc:  # report the section, then fail the run
+            print(f"tpu,0,ERROR {type(exc).__name__}: {exc}")
+            failed.append("tpu")
+        else:
+            for row in rows:
                 print(row)
-        except ImportError:
-            pass
     print(f"total.elapsed,{(time.time() - t0) * 1e6:.0f},"
           f"s={time.time() - t0:.1f}", file=sys.stderr)
-
+    if failed:
+        print(f"failed sections: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 if __name__ == "__main__":
     main()

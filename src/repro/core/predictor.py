@@ -31,13 +31,6 @@ SHT_DEFAULT_ENTRIES = 512  # paper Table 2: 512-entry Sector Predictor
 LA_DEFAULT_WINDOW = 128  # paper Table 2: 128-entry LSQ Lookahead
 
 
-def _reduce_or(x, axis: int = 0):
-    """Bitwise-OR reduction; jax.lax.reduce_or only exists in newer JAX."""
-    if hasattr(jax.lax, "reduce_or"):
-        return jax.lax.reduce_or(x, axes=(axis,))
-    return jax.lax.reduce(x, x.dtype.type(0), jax.lax.bitwise_or, (axis,))
-
-
 @dataclasses.dataclass(frozen=True)
 class FetchPolicy:
     """What the memory controller fetches per miss — one per evaluated config.
@@ -119,7 +112,7 @@ def _simulate_core(pc, first_word, used_mask, dist, dirty_mask, *,
         n_extra, masks, dists = lsq.cluster_requests(
             e_used, e_dist, m0, la_window, chop=chop
         )
-        fetched = m0 | _reduce_or(masks, axis=0)
+        fetched = m0 | jax.lax.reduce_or(masks, axes=(0,))
         overfetch = popcount8(fetched & ~e_used)
         # SHT learns the words used during this residency (Fig. 8, item 4).
         table = table.at[idx].set(e_used)

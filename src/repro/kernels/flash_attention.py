@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend
+
 NEG_INF = -1e30
 
 
@@ -62,12 +64,22 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref,
             out_ref.dtype)
 
 
+def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
+                    block_k: int = 128, interpret: bool | None = None):
+    """q,k,v: (B, H, S, hd) -> (B, H, S, hd) in q.dtype.
+
+    ``interpret=None`` auto-detects: compiled on TPU, interpreter elsewhere.
+    """
+    return _flash_attention(q, k, v, causal=causal, block_q=block_q,
+                            block_k=block_k,
+                            interpret=backend.resolve_interpret(interpret))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret"))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
-    """q,k,v: (B, H, S, hd) -> (B, H, S, hd) in q.dtype."""
+def _flash_attention(q, k, v, causal: bool, block_q: int, block_k: int,
+                     interpret: bool):
     B, H, S, hd = q.shape
     bq = min(block_q, S)
     bk = min(block_k, S)

@@ -27,15 +27,19 @@ def sectored_attention_ref(q, k_pages, v_pages, page_idx, length):
     k_sel = jnp.take_along_axis(
         k_pages, page_idx[..., None, None], axis=2)  # (B,Hkv,K,page,hd)
     v_sel = jnp.take_along_axis(v_pages, page_idx[..., None, None], axis=2)
+    K = page_idx.shape[-1]
     scores = jnp.einsum("bgrk,bgcpk->bgrcp", q.astype(jnp.float32),
                         k_sel.astype(jnp.float32)) / jnp.sqrt(jnp.float32(hd))
+    # the K selected pages form one flat (K * page) softmax axis
+    scores = scores.reshape(B, Hkv, -1, K * page)
     tok_pos = page_idx[..., None] * page + jnp.arange(page)
-    valid = tok_pos < length[:, None, None, None]
-    scores = jnp.where(valid[:, :, None, :, :], scores, NEG_INF)
-    m = jnp.max(scores, axis=(-2, -1), keepdims=True)
-    e = jnp.where(valid[:, :, None, :, :], jnp.exp(scores - m), 0.0)
-    num = jnp.einsum("bgrcp,bgcpk->bgrk", e, v_sel.astype(jnp.float32))
-    den = jnp.maximum(jnp.sum(e, axis=(-2, -1)), 1e-30)
+    valid = (tok_pos < length[:, None, None, None]).reshape(B, Hkv, 1, -1)
+    scores = jnp.where(valid, scores, NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    e = jnp.where(valid, jnp.exp(scores - m), 0.0)
+    num = jnp.einsum("bgrs,bgsk->bgrk", e,
+                     v_sel.astype(jnp.float32).reshape(B, Hkv, K * page, hd))
+    den = jnp.maximum(jnp.sum(e, axis=-1), 1e-30)
     return num / den[..., None]
 
 

@@ -1,9 +1,9 @@
 """Pallas TPU kernels: sectored decode attention (the paper's SA+VBL on TPU).
 
-Hardware mapping (DESIGN.md §2): the Sector Predictor's page indices are
+Hardware mapping: the Sector Predictor's page indices are
 *scalar-prefetched* so they can steer the BlockSpec index_map — the grid
-walks (batch, kv-head, selected-sector) and the DMA engine brings exactly
-one selected KV page HBM->VMEM per step. Pages that are not selected are
+walks (batch, kv-head, selected-sector) and the DMA engine brings one
+selected KV page HBM->VMEM per step. Pages that are not selected are
 never read from HBM at all: that is Sectored Activation + Variable Burst
 Length — the burst (pipeline of page DMAs) has data-dependent length K
 instead of the full sequence.
@@ -15,21 +15,26 @@ Two entry points share the steering machinery:
   ``kernels/ref.py:sectored_attention_ref`` in tier-1.
 * :func:`sectored_attention_paged` — the serving kernel over the runtime's
   page-major cache view ((B, P, page, Hkv, hd), a free reshape of the
-  (B, S, Hkv, hd) decode cache). Its unquantized arithmetic mirrors
-  ``runtime/sectored_decode.py:sectored_attend`` operand-for-operand (bf16
-  matmul operands, f32 accumulation, identical mask/softmax/mass
-  formulation), so the fused serving path is bit-exact with the dispatch
-  path; with int8 pages + per-sector scales it dequantizes in the f32
-  accumulate (tolerance-gated, see kernels/quantized_kv.py).
+  (B, S, Hkv, hd) decode cache). Its KV block is one page for every kv
+  head, (page, Hkv, hd) — the last two dims full, as the TPU tiling rule
+  wants — and the kernel picks head h's rows in VMEM. Its unquantized
+  arithmetic mirrors ``runtime/sectored_decode.py:sectored_attend``
+  operand-for-operand (bf16 matmul operands, f32 accumulation, identical
+  mask/softmax/mass formulation), so the fused serving path is bit-exact
+  with the dispatch path in interpret mode; compiled, the two agree
+  within a tolerance. With int8 pages + per-sector scales it dequantizes
+  in the f32 accumulate (tolerance-gated, see kernels/quantized_kv.py).
 
 Softmax note: both kernels stream each fetched page's masked scores (and
 its V page) into VMEM scratch and run ONE global softmax + contraction at
 the final grid step, rather than the online max/rescale recurrence. An
 online softmax multiplies the accumulator by ``exp(m_prev - m_new)`` per
 page — a different float expression tree from the two-pass softmax of the
-dispatch path, so it can never be bitwise against it. The scratch cost is
-(rep x K x page) f32 scores + (K x page x hd) V — for serving budgets
-(K ~ P/8 pages of 128 x 128 bf16) comfortably inside the ~16 MiB VMEM.
+dispatch path, so it can never be bitwise against it. The scratch holds
+the K pages along one flat axis — (rep, K * page) f32 scores, (K * page,
+hd) V — so the softmax reduces one axis and the contraction is a single
+matmul, both of which Mosaic lowers; for serving budgets (K ~ P/8 pages
+of 128 x 128 bf16) it sits comfortably inside the ~16 MiB VMEM.
 
 Length convention: ``length`` is the **count** of valid tokens — positions
 ``0 .. length-1`` exist, mask is ``tok_pos < length``. This matches
@@ -77,32 +82,70 @@ def _check_page_idx(page_idx, hkv: int) -> bool:
     return heads == 1 and hkv > 1
 
 
-def _global_softmax_attend(scores, vmask, v_pages):
+def _scratch_shapes(rep: int, num_selected: int, page: int, hd: int,
+                    v_dtype):
+    """Per-(b, h) VMEM scratch both kernels stream pages into, laid out
+    for the final contraction: masked scores (rep, K * page), their
+    validity (1, K * page), and V (K * page, hd). Page ``i`` lands at the
+    128-aligned offset ``i * page`` of the flat axis."""
+    return [pltpu.VMEM((rep, num_selected * page), jnp.float32),
+            pltpu.VMEM((1, num_selected * page), jnp.float32),
+            pltpu.VMEM((num_selected * page, hd), v_dtype)]
+
+
+def _stream_page(i, s, valid, v, s_scr, valid_scr, v_scr, *, page: int):
+    """Store fetched page ``i``'s masked scores, validity and V rows."""
+    off = pl.multiple_of(i * page, page)
+    s_scr[:, pl.ds(off, page)] = jnp.where(valid, s, NEG_INF)
+    valid_scr[:, pl.ds(off, page)] = valid[:1].astype(jnp.float32)
+    v_scr[pl.ds(off, page), :] = v
+
+
+def _global_softmax_attend(s_scr, valid_scr, v_scr, *, page: int):
     """The final-step softmax + contraction both kernels share.
 
-    scores: (rep, K, page) f32, invalid positions already NEG_INF.
-    vmask:  (K, page) f32 (1.0 = valid).
-    v_pages: (K, page, hd) — bf16 on the serving path (matching the
-    dispatch path's ``e.astype(v.dtype)`` operand cast), f32 on the
-    reference/quantized paths.
+    The scores of all K fetched pages form one flat (rep, K * page) row
+    per query head: the max, the exp and the denominator run over that
+    axis, and the V contraction is a single-axis matmul (rep, K * page)
+    @ (K * page, hd) — shapes Mosaic lowers. The dispatch path reduces
+    over the same flat axis, so the two agree op for op. ``e`` meets V
+    in V's dtype (bf16 on the serving path, like the dispatch path's
+    ``e.astype(v.dtype)``; f32 on the reference and quantized paths).
 
-    Returns (out (rep, hd) f32, e (rep, K, page) f32). Op-for-op the
-    per-(b, h) slice of the batched formulation in ``sectored_attend`` /
-    ``sectored_attention_ref`` — verified bitwise in tier-1.
+    Returns ``(out (rep, hd) f32, mass (1, K) f32)``: ``mass`` is each
+    page's share of the attention mass summed over the q-head group —
+    the SHT update's input, same expression as the dispatch path's.
     """
-    valid = vmask != 0.0
-    m = jnp.max(scores, axis=(-2, -1), keepdims=True)
-    e = jnp.where(valid[None], jnp.exp(scores - m), 0.0)
-    num = jnp.einsum("rcp,cpk->rk", e.astype(v_pages.dtype), v_pages,
-                     preferred_element_type=jnp.float32)
-    den = jnp.maximum(jnp.sum(e, axis=(-2, -1)), 1e-30)
-    return num / den[..., None], e
+    scores = s_scr[...]
+    valid = jnp.broadcast_to(valid_scr[...] != 0.0, scores.shape)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    e = jnp.where(valid, jnp.exp(scores - m), 0.0)
+    v = v_scr[...]
+    num = jnp.dot(e.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    den = jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-30)
+    num_selected = scores.shape[-1] // page
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, num_selected), 1)
+    mass = jnp.zeros((1, num_selected), jnp.float32)
+    for c in range(num_selected):  # static: K pages per step budget
+        mass = mass + jnp.where(
+            lane == c, jnp.sum(e[:, c * page:(c + 1) * page]), 0.0)
+    return num / den, mass / jnp.maximum(jnp.sum(e), 1e-30)
+
+
+def _head_rows(block, h):
+    """Head ``h``'s (page, hd) rows of a full-head (page, Hkv, hd) block,
+    as f32. A one-hot select + sum over the head axis: exact (one
+    non-zero term), and unlike a dynamic index on the second-minor axis
+    it lowers under Mosaic."""
+    full = block.astype(jnp.float32)
+    head = jax.lax.broadcasted_iota(jnp.int32, full.shape, 1)
+    return jnp.sum(jnp.where(head == h, full, 0.0), axis=1)
 
 
 def _ref_kernel(pages_ref, length_ref,  # scalar prefetch
                 q_ref, k_ref, v_ref,  # VMEM blocks
                 out_ref,  # VMEM output block
-                s_scr, v_scr, valid_scr,  # scratch
+                s_scr, valid_scr, v_scr,  # scratch
                 *, page_size: int, num_selected: int, shared_pages: bool):
     b = pl.program_id(0)
     h = 0 if shared_pages else pl.program_id(1)
@@ -111,18 +154,15 @@ def _ref_kernel(pages_ref, length_ref,  # scalar prefetch
     q = q_ref[0, 0].astype(jnp.float32)  # (rep, hd)
     k = k_ref[0, 0, 0].astype(jnp.float32)  # (page, hd)
     s = jnp.einsum("rk,pk->rp", q, k) / jnp.sqrt(jnp.float32(q.shape[-1]))
-
-    page_id = pages_ref[b, h, i]
-    tok_pos = page_id * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    valid = tok_pos < length_ref[b]
-    s_scr[:, i, :] = jnp.where(valid, s, NEG_INF)
-    valid_scr[i, :] = valid[0].astype(jnp.float32)
-    v_scr[i] = v_ref[0, 0, 0].astype(jnp.float32)
+    valid = _vbl_window(pages_ref[b, h, i], length_ref, b, s.shape,
+                        page_size=page_size)
+    _stream_page(i, s, valid, v_ref[0, 0, 0].astype(jnp.float32),
+                 s_scr, valid_scr, v_scr, page=page_size)
 
     @pl.when(i == num_selected - 1)
     def _finish():
-        out, _ = _global_softmax_attend(s_scr[...], valid_scr[...], v_scr[...])
+        out, _ = _global_softmax_attend(s_scr, valid_scr, v_scr,
+                                        page=page_size)
         out_ref[0, 0] = out
 
 
@@ -135,44 +175,44 @@ def _vbl_window(page_id, length_ref, b, shape, *, page_size: int):
     return tok_pos < length_ref[b]
 
 
-def _paged_kernel(pages_ref, length_ref, q_ref, k_ref, v_ref, *rest,
-                  page_size: int, num_selected: int, shared_pages: bool,
-                  quantized: bool):
+def _paged_kernel(pages_ref, length_ref, *refs, page_size: int,
+                  num_selected: int, shared_pages: bool, quantized: bool):
     if quantized:
-        (ks_ref, vs_ref, out_ref, mass_ref,
-         s_scr, v_scr, valid_scr) = rest
+        (ks_ref, vs_ref, q_ref, k_ref, v_ref, out_ref, mass_ref,
+         s_scr, valid_scr, v_scr) = refs
     else:
-        out_ref, mass_ref, s_scr, v_scr, valid_scr = rest
+        (q_ref, k_ref, v_ref, out_ref, mass_ref,
+         s_scr, valid_scr, v_scr) = refs
     b = pl.program_id(0)
-    h = 0 if shared_pages else pl.program_id(1)
+    h = pl.program_id(1)
     i = pl.program_id(2)
+    page_id = pages_ref[b, 0 if shared_pages else h, i]
 
     q = q_ref[0, 0]  # (rep, hd) — bf16 operand, like the dispatch path
-    k = k_ref[0, 0, :, 0]  # (page, hd)
-    v = v_ref[0, 0, :, 0]
+    # the block holds every kv head of the selected page: bf16 packs head
+    # pairs into one word, so a single head is not a DMA-able slice
+    k = _head_rows(k_ref[0, 0], h)  # (page, hd) f32, exact
+    v = _head_rows(v_ref[0, 0], h)
     if quantized:
         # dequant in the f32 accumulate: the sector's payload arrived as
         # int8 (half the burst bytes) with its one per-(page, head) scale
         q = q.astype(jnp.float32)
-        k = k.astype(jnp.float32) * ks_ref[0, 0, 0]
-        v = v.astype(jnp.float32) * vs_ref[0, 0, 0]
+        k = k * ks_ref[b, page_id, h]
+        v = v * vs_ref[b, page_id, h]
+    else:
+        k = k.astype(k_ref.dtype)
+        v = v.astype(v_ref.dtype)
     s = jnp.einsum("rk,pk->rp", q, k, preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(q.shape[-1]))
-
-    page_id = pages_ref[b, h, i]
     valid = _vbl_window(page_id, length_ref, b, s.shape, page_size=page_size)
-    s_scr[:, i, :] = jnp.where(valid, s, NEG_INF)
-    valid_scr[i, :] = valid[0].astype(jnp.float32)
-    v_scr[i] = v
+    _stream_page(i, s, valid, v, s_scr, valid_scr, v_scr, page=page_size)
 
     @pl.when(i == num_selected - 1)
     def _finish():
-        out, e = _global_softmax_attend(s_scr[...], valid_scr[...], v_scr[...])
+        out, mass = _global_softmax_attend(s_scr, valid_scr, v_scr,
+                                           page=page_size)
         out_ref[0, 0] = out
-        # per-page attention mass for the SHT update, summed over the
-        # q-head group — same expression as the dispatch path's step 4
-        mass_ref[0, 0] = jnp.sum(e, axis=(0, 2)) / jnp.maximum(
-            jnp.sum(e), 1e-30)
+        mass_ref[0, 0] = mass
 
 
 @functools.partial(jax.jit, static_argnames=("shared", "interpret"))
@@ -195,11 +235,7 @@ def _sectored_attention(q, k_pages, v_pages, page_idx, length, shared: bool,
         ],
         out_specs=pl.BlockSpec((1, 1, rep, hd),
                                lambda b, h, i, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, K, page), jnp.float32),
-            pltpu.VMEM((K, page, hd), jnp.float32),
-            pltpu.VMEM((K, page), jnp.float32),
-        ],
+        scratch_shapes=_scratch_shapes(rep, K, page, hd, jnp.float32),
     )
     kernel = functools.partial(_ref_kernel, page_size=page,
                                num_selected=K, shared_pages=shared)
@@ -246,47 +282,46 @@ def _sectored_attention_paged(q, k_pages, v_pages, page_idx, length,
     K = page_idx.shape[-1]
     quantized = k_scale is not None
 
-    def kv_map(b, h, i, pages, length):
-        return (b, pages[b, 0 if shared else h, i], 0, h, 0)
+    def kv_map(b, h, i, pages, *_):
+        return (b, pages[b, 0 if shared else h, i], 0, 0, 0)
 
-    def scale_map(b, h, i, pages, length):
-        return (b, pages[b, 0 if shared else h, i], h)
+    def head_map(b, h, i, *_):
+        return (b, h, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, rep, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page, 1, hd), kv_map),
-        pl.BlockSpec((1, 1, page, 1, hd), kv_map),
-    ]
-    operands = [page_idx, length, q, k_pages, v_pages]
+    prefetch = [page_idx, length]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, 1), scale_map),
-                     pl.BlockSpec((1, 1, 1), scale_map)]
-        operands += [k_scale, v_scale]
+        # per-(sequence, page, head) scales ride in SMEM next to the page
+        # table: one scalar per fetched sector, read at the page's step
+        prefetch += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, Hkv, K),
-        in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, 1, rep, hd), lambda b, h, i, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, K), lambda b, h, i, *_: (b, h, 0)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((rep, K, page), jnp.float32),
-            pltpu.VMEM((K, page, hd),
-                       jnp.float32 if quantized else k_pages.dtype),
-            pltpu.VMEM((K, page), jnp.float32),
+        in_specs=[
+            pl.BlockSpec((1, 1, rep, hd), head_map),
+            # last two block dims are the full (Hkv, hd): the page's rows
+            # for every kv head, one contiguous DMA of the free
+            # (B, P, page, Hkv, hd) view of the decode cache
+            pl.BlockSpec((1, 1, page, Hkv, hd), kv_map),
+            pl.BlockSpec((1, 1, page, Hkv, hd), kv_map),
         ],
+        out_specs=(
+            pl.BlockSpec((1, 1, rep, hd), head_map),
+            pl.BlockSpec((1, 1, 1, K), head_map),
+        ),
+        scratch_shapes=_scratch_shapes(
+            rep, K, page, hd, jnp.float32 if quantized else v_pages.dtype),
     )
     kernel = functools.partial(_paged_kernel, page_size=page,
                                num_selected=K, shared_pages=shared,
                                quantized=quantized)
-    return pl.pallas_call(
+    out, mass = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(jax.ShapeDtypeStruct((B, Hkv, rep, hd), jnp.float32),
-                   jax.ShapeDtypeStruct((B, Hkv, K), jnp.float32)),
+                   jax.ShapeDtypeStruct((B, Hkv, 1, K), jnp.float32)),
         interpret=interpret,
-    )(*operands)
+    )(*prefetch, q, k_pages, v_pages)
+    return out, mass.reshape(B, Hkv, K)
 
 
 def sectored_attention_paged(q, k_pages, v_pages, page_idx, length, *,

@@ -2,11 +2,6 @@
 
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state — smoke tests must keep seeing 1 CPU device.
-
-``jax.sharding.AxisType`` (explicit/auto axis semantics) only exists in newer
-JAX releases; on older installs we fall back to a plain ``jax.make_mesh`` (or
-a hand-built ``Mesh``) without axis types, which is semantically the old
-implicit-Auto behaviour.
 """
 
 from __future__ import annotations
@@ -15,19 +10,12 @@ import numpy as np
 
 import jax
 
-AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def _mesh(shape, axes):
-    """jax.make_mesh with AxisType.Auto when available, plain mesh otherwise."""
+    """jax.make_mesh with implicit (Auto) axis semantics."""
     shape, axes = tuple(shape), tuple(axes)
-    if AXIS_TYPE is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AXIS_TYPE.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

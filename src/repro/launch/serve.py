@@ -67,6 +67,7 @@ request (default 1 = all), demonstrating mixed greedy+sampled waves.
 from __future__ import annotations
 
 import argparse
+import functools
 
 import jax
 import numpy as np
@@ -74,6 +75,7 @@ import numpy as np
 from repro import configs
 from repro.core import metrics
 from repro.launch import mesh as mesh_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.runtime import sectored_decode
 from repro.obs import (FlightRecorder, MetricsRegistry, write_jsonl,
@@ -113,13 +115,13 @@ def build_backend(cfg, params, *, sectored=True, true_sectored=False,
             backend.sectored_fn = None
         return backend
 
-    @jax.jit
-    def prefill_fn(tokens):
-        return model.prefill(params, cfg, tokens)
-
-    @jax.jit
-    def decode_fn(state, token):
-        return model.decode_step(params, cfg, state, token)
+    # weights bound outside jit, so the waves built on these steps take
+    # them as an argument (serve.backend.split_bound)
+    prefill_fn = functools.partial(
+        jax.jit(lambda p, tokens: model.prefill(p, cfg, tokens)), params)
+    decode_fn = functools.partial(
+        jax.jit(lambda p, state, token: model.decode_step(p, cfg, state,
+                                                          token)), params)
 
     sect_fn = None
     if sectored and not cfg.attn_free and not cfg.layer_pattern:
@@ -346,6 +348,7 @@ def main(argv=None):
         ap.error("--shared-prefix needs --prefix-cache (shared tokens "
                  "without a cache would just be re-prefilled every time)")
 
+    enable_compile_cache()
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

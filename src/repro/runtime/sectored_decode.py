@@ -33,6 +33,7 @@ since the fused-selection pipeline was promoted out of ``MeshBackend``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -188,26 +189,32 @@ def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
     # selected (exact mode) the gathered buffer is the dense cache prefix in
     # ascending-page order, so the logits are bit-exact with the dense path.
     qg = q[:, 0].reshape(B, hkv, rep, hd)
+    K = pages.shape[-1]
     scores = jnp.einsum("bgrk,bgcpk->bgrcp", qg.astype(k_sel.dtype), k_sel,
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
+    # the K gathered pages form one flat (K * page) softmax axis, as in
+    # the fused kernel and the dense decode_attend
+    scores = scores.reshape(B, hkv, rep, K * PAGE_SIZE)
     # causal/validity mask on absolute token positions
     tok_pos = pages[..., None] * PAGE_SIZE + jnp.arange(PAGE_SIZE)  # (B,H,K,p)
-    valid = tok_pos <= cache.length[:, None, None, None]
-    scores = jnp.where(valid[:, :, None, :, :], scores, NEG_INF)
-    m = jnp.max(scores, axis=(-2, -1), keepdims=True)
+    valid = (tok_pos <= cache.length[:, None, None, None]).reshape(
+        B, hkv, 1, K * PAGE_SIZE)
+    scores = jnp.where(valid, scores, NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - jax.lax.stop_gradient(m))
-    e = jnp.where(valid[:, :, None, :, :], e, 0.0)
-    num = jnp.einsum("bgrcp,bgcpk->bgrk", e.astype(v_sel.dtype), v_sel,
+    e = jnp.where(valid, e, 0.0)
+    num = jnp.einsum("bgrs,bgsk->bgrk", e.astype(v_sel.dtype),
+                     v_sel.reshape(B, hkv, K * PAGE_SIZE, hd),
                      preferred_element_type=jnp.float32)
-    den = jnp.sum(e, axis=(-2, -1))[..., None]
+    den = jnp.sum(e, axis=-1)[..., None]
     out = (num / jnp.maximum(den, 1e-30)).astype(x.dtype)
     out = out.reshape(B, 1, cfg.n_heads, hd)
     out = jnp.einsum("bqhk,hkd->bqd", out, attn_params["wo"])
 
     # 4. SHT update: per-page attention mass (summed over q-head group)
-    mass = jnp.sum(e, axis=(2, 4)) / jnp.maximum(
-        jnp.sum(e, axis=(2, 3, 4))[..., None], 1e-30)  # (B, Hkv, K)
+    mass = jnp.sum(e.reshape(B, hkv, rep, K, PAGE_SIZE), axis=(2, 4)) / (
+        jnp.maximum(jnp.sum(e, axis=(2, 3))[..., None], 1e-30))  # (B,Hkv,K)
     new_table = sector_predictor.update(table_l, pages, mass)
 
     new_cache = attention.KVCache(k=k, v=v, length=cache.length + 1)
@@ -254,10 +261,17 @@ def _attend_fused(attn_params, cfg, x, q, k, v, cache, table_l, page_idx,
     return out, new_cache, new_table
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "k_pages", "probe", "kernel"))
 def sectored_decode_step(params, cfg, state: SectoredState, token,
                          k_pages: int, probe: bool = False,
                          kernel: str = "dispatch"):
     """Full-model one-token decode with sectored attention per layer.
+
+    Jitted with the configuration static and the weights an argument, so
+    a caller that steps token by token compiles once per configuration
+    and state shape — not once per call, as an eager ``lax.scan`` over a
+    fresh layer-body closure would.
 
     ``probe`` forwards to :func:`sectored_attend` — default off, so direct
     callers (the exact-mode oracle, mesh factories, prefill scans) keep
@@ -292,6 +306,14 @@ def sectored_decode_step(params, cfg, state: SectoredState, token,
     new = SectoredState(kv=kv_new, table=table_new,
                         position=state.position + 1)
     return logits, new
+
+
+def _serving_step(params, state, token, *, cfg, k_pages: int, probe: bool,
+                  kernel: str):
+    """:func:`sectored_decode_step` in the ``(params, state, token)``
+    order the serving backend binds its weights into."""
+    return sectored_decode_step(params, cfg, state, token, k_pages,
+                                probe=probe, kernel=kernel)
 
 
 def make_sectored_decode_step(cfg, mesh, *, batch: int, seq_len: int,
@@ -389,22 +411,23 @@ class SectoredKVBackend(ServingBackend):
         # prefill (on the admission critical path) and looped-wave decode
         # don't pay per-op eager dispatch for a full model traversal
         exact_fn = self._step_for(self.pages)  # every page: exact mode
-        super().__init__(self._prefill, exact_fn,
+        super().__init__(functools.partial(self._prefill, params), exact_fn,
                          self._step_for(self.k_for(topk_frac)),
                          or_merge_demands, vocab=cfg.vocab)
 
     def _step_for(self, k_pages: int):
         fn = self._k_cache.get(k_pages)
         if fn is None:
-            cfg, params = self.cfg, self.params
             # genuinely narrow budgets widen by one probe page per wave so
             # the SHT stays honest on long narrow runs; exact mode
             # (k == pages) stays probe-free and bit-exact with dense
             probe = self.probe_pages_for(k_pages) > 0
             kernel = self.kernel if 0 < k_pages < self.pages else "dispatch"
-            fn = jax.jit(lambda state, token: sectored_decode_step(
-                params, cfg, state, token, k_pages, probe=probe,
-                kernel=kernel))
+            # weights bound outside jit: a wave that vmaps this step
+            # passes them to its own jit as an argument (split_bound)
+            fn = functools.partial(_serving_step, self.params,
+                                   cfg=self.cfg, k_pages=k_pages,
+                                   probe=probe, kernel=kernel)
             self._k_cache[k_pages] = fn
         return fn
 
@@ -442,39 +465,35 @@ class SectoredKVBackend(ServingBackend):
             return self.sectored_fn
         return self._step_for(self.k_for(topk_frac))
 
-    def _prefill(self, tokens):
-        """Exact-mode prefill as ONE jitted ``lax.scan`` over the prompt
-        (compiled per prompt length). The scan body is the same exact-mode
-        step the dense decode path runs, so prefill numerics are shared by
-        every scheduler/policy combination; the scan replaces the former
-        per-token Python loop of jitted steps (S dispatches -> 1), which
-        multi-page prompts (energy benchmarks, long-context serving) made
-        an admission bottleneck.
-        """
+    def _scan_prompt(self, params, state, tokens):
+        """Exact-mode steps over a prompt as ONE ``lax.scan`` (the same
+        step the dense decode path runs, so prefill numerics are shared
+        by every scheduler/policy combination). Returns the last token's
+        logits and the state."""
+        cfg, k_pages = self.cfg, self.pages
+        logits, state = sectored_decode_step(params, cfg, state,
+                                             tokens[:, :1], k_pages)
+
+        def body(carry, tok):
+            _, state = carry
+            return sectored_decode_step(params, cfg, state, tok[:, None],
+                                        k_pages), None
+
+        (logits, state), _ = jax.lax.scan(body, (logits, state),
+                                          tokens[:, 1:].T)
+        return logits, state
+
+    def _prefill(self, params, tokens):
+        """Exact-mode prefill of a fresh state, jitted per prompt length
+        (the session binds ``params``: ``prefill_fn``)."""
         tokens = jnp.asarray(tokens, jnp.int32)
         fn = self._prefill_cache.get(tokens.shape[1])
         if fn is None:
-            cfg, params = self.cfg, self.params
-            seq_len, k_pages = self.seq_len, self.pages
-
-            def prefill(tokens):
-                state = init_state(cfg, tokens.shape[0], seq_len)
-                logits, state = sectored_decode_step(
-                    params, cfg, state, tokens[:, :1], k_pages)
-
-                def body(carry, tok):
-                    _, state = carry
-                    logits, state = sectored_decode_step(
-                        params, cfg, state, tok[:, None], k_pages)
-                    return (logits, state), None
-
-                (logits, state), _ = jax.lax.scan(
-                    body, (logits, state), tokens[:, 1:].T)
-                return logits, state
-
-            fn = jax.jit(prefill)
+            fn = jax.jit(lambda params, tokens: self._scan_prompt(
+                params, init_state(self.cfg, tokens.shape[0], self.seq_len),
+                tokens))
             self._prefill_cache[tokens.shape[1]] = fn
-        return fn(tokens)
+        return fn(params, tokens)
 
     # -- prefix-cache hooks (serve.prefix.PrefixCache warm admission) ------
 
@@ -505,34 +524,17 @@ class SectoredKVBackend(ServingBackend):
         """Resume exact-mode prefill from a seeded state (warm admission:
         only the un-matched prompt suffix is re-prefilled).
 
-        Same scan body as :meth:`_prefill` — the exact-mode decode step —
-        but starting from ``state`` instead of a fresh one, so (seed at
-        n) + (suffix scan) is bitwise the cold full prefill. Jitted per
-        suffix length.
+        Same scan as :meth:`_prefill` — the exact-mode decode step — but
+        starting from ``state`` instead of a fresh one, so (seed at n) +
+        (suffix scan) is bitwise the cold full prefill. Jitted per suffix
+        length.
         """
         tokens = jnp.asarray(tokens, jnp.int32)
         fn = self._suffix_cache.get(tokens.shape[1])
         if fn is None:
-            cfg, params = self.cfg, self.params
-            k_pages = self.pages
-
-            def suffix(state, tokens):
-                logits, state = sectored_decode_step(
-                    params, cfg, state, tokens[:, :1], k_pages)
-
-                def body(carry, tok):
-                    _, state = carry
-                    logits, state = sectored_decode_step(
-                        params, cfg, state, tok[:, None], k_pages)
-                    return (logits, state), None
-
-                (logits, state), _ = jax.lax.scan(
-                    body, (logits, state), tokens[:, 1:].T)
-                return logits, state
-
-            fn = jax.jit(suffix)
+            fn = jax.jit(self._scan_prompt)
             self._suffix_cache[tokens.shape[1]] = fn
-        return fn(state, tokens)
+        return fn(self.params, state, tokens)
 
 
 def make_serving_fns(cfg, *, params, seq_len: int,

@@ -27,6 +27,7 @@ This module is deliberately leaf-level: it imports nothing from
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Iterator, Protocol, runtime_checkable
 
 import jax
@@ -142,16 +143,48 @@ class ServingBackend:
                 f"merge={self.demand_merge_fn is not None})")
 
 
+# -- weights as arguments ---------------------------------------------------
+
+
+def split_bound(fn: Callable) -> tuple[Callable, tuple]:
+    """Split a step bound with :func:`functools.partial` into ``(step,
+    bound args)``.
+
+    Serving steps bind their weights as ``partial(step, params)`` so the
+    session can call them as ``(state, token)``. A program that calls such
+    a step inside its own ``jax.jit`` — a wave, a vmapped prefill — must
+    not close over the weights: ``jit`` embeds closed-over arrays in the
+    program as constants, and at real widths that is gigabytes per
+    program. It passes the bound args to its ``jit`` as arguments instead
+    (unbatched under ``vmap``). A plain callable binds nothing.
+    """
+    if isinstance(fn, functools.partial):
+        return functools.partial(fn.func, **fn.keywords), fn.args
+    return fn, ()
+
+
+def vmapped_prefill(prefill_fn: Callable) -> tuple[Callable, tuple]:
+    """``(jitted, bound)``: ``jitted(bound, prompts)`` prefills a stack of
+    equal-length prompts ``(n, S)`` in ONE dispatch, the prefill's bound
+    weights passed as an argument."""
+    step, bound = split_bound(prefill_fn)
+    jitted = jax.jit(jax.vmap(lambda b, p: step(*b, p[None, :]),
+                              in_axes=(None, 0)))
+    return jitted, bound
+
+
 # -- fused wave pipeline (shared by single-device sessions + MeshBackend) ----
 
 
 def fused_select_step(fn: Callable, *, sampled: bool = False) -> Callable:
     """Per-slot decode step with token selection fused in.
 
-    Wraps ``fn(state, token) -> (logits, new_state)`` into
-    ``fused(state, token, row) -> (tok, new_state, advanced_row)`` where
-    ``tok`` keeps the token's ``(1, 1)`` row shape so a stacked wave
-    output can feed the next wave directly (device-side token feedback).
+    Wraps ``fn(*bound, state, token) -> (logits, new_state)`` into
+    ``fused(bound, state, token, row) -> (tok, new_state, advanced_row)``
+    where ``bound`` is the tuple of leading args :func:`split_bound` split
+    off (the weights; ``()`` for a plain step) and ``tok`` keeps the
+    token's ``(1, 1)`` row shape so a stacked wave output can feed the
+    next wave directly (device-side token feedback).
 
     ``sampled=False`` builds the pure greedy pipeline — per-slot
     first-max argmax exactly like the host ``np.argmax`` it replaces,
@@ -189,8 +222,8 @@ def fused_select_step(fn: Callable, *, sampled: bool = False) -> Callable:
             return jnp.argmax(
                 logits.reshape(-1, logits.shape[-1])[0]).astype(jnp.int32)
 
-    def fused(state, token, row: SamplerRows):
-        logits, new_state = fn(state, token)
+    def fused(bound, state, token, row: SamplerRows):
+        logits, new_state = fn(*bound, state, token)
         tok = select(logits, row).reshape(1, 1)
         stopped = jnp.any(token.reshape(-1)[-1] == row.stop)
         tok = jnp.where(stopped, token.reshape(1, 1), tok)
@@ -206,6 +239,17 @@ def fused_select_step(fn: Callable, *, sampled: bool = False) -> Callable:
     return fused
 
 
+def fused_wave_program(fn: Callable, *, sampled: bool = False
+                       ) -> tuple[Callable, tuple]:
+    """``(jitted, bound)``: ``jitted(bound, stacked, tokens, rows)`` is the
+    ``jit(vmap)`` of :func:`fused_select_step` over the slot axis, with
+    the step's bound weights an unbatched argument."""
+    step, bound = split_bound(fn)
+    jitted = jax.jit(jax.vmap(fused_select_step(step, sampled=sampled),
+                              in_axes=(None, 0, 0, 0)))
+    return jitted, bound
+
+
 def make_fused_wave(fn: Callable, *, sampled: bool = False) -> Callable:
     """Default (single-device) fused wave: ``jit(vmap)`` of
     :func:`fused_select_step`, advertising ``returns_tokens``.
@@ -216,10 +260,10 @@ def make_fused_wave(fn: Callable, *, sampled: bool = False) -> Callable:
     Memoization is the caller's job (``ServeSession._wave_for`` caches
     per ``(id(fn), sampled)``).
     """
-    jitted = jax.jit(jax.vmap(fused_select_step(fn, sampled=sampled)))
+    jitted, bound = fused_wave_program(fn, sampled=sampled)
 
     def wave(stacked, tokens, rows: SamplerRows):
-        return jitted(stacked, tokens, rows)
+        return jitted(bound, stacked, tokens, rows)
 
     wave.returns_tokens = True
     return wave

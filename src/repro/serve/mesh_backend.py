@@ -45,7 +45,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel import sharding
-from repro.serve.backend import fused_select_step
+from repro.serve.backend import (fused_wave_program, split_bound,
+                                 vmapped_prefill)
 
 
 class MeshBackend:
@@ -81,7 +82,8 @@ class MeshBackend:
         self._donor = (devices[-1] if donor_prefill else devices[0])
         self._donor_sharding = jax.sharding.SingleDeviceSharding(self._donor)
         self._sharding_cache: dict[tuple, Any] = {}
-        self._vp_jit: Callable | None = None
+        self._vp_jit: tuple[Callable, tuple] | None = None
+        self._bound_cache: dict[tuple, Any] = {}
         self.prefill_fn = self._donor_prefill
         # NOTE: a meter's mesh_shape provenance stamp is owned by the
         # ServeSession that actually drives waves (it clears the stamp
@@ -150,10 +152,11 @@ class MeshBackend:
         """Mesh-placed jitted wave for a per-slot step fn.
 
         Builds the SAME fused pipeline every vectorized session runs
-        (``serve.backend.fused_select_step`` — token selection inside the
+        (``serve.backend.fused_wave_program`` — token selection inside the
         wave executable, ``returns_tokens = True``; ``sampled`` picks
         greedy argmax or the ``repro.sample`` kernel) and adds placement:
-        the stacked state, token batch, and sampler rows are pinned to
+        the step's weights are replicated over the mesh once, and the
+        stacked state, token batch, and sampler rows are pinned to
         their mesh shardings before each dispatch (output shardings
         propagate, so steady-state waves pay no transfers). Each shard
         selects its own slots' tokens locally, so ONE dispatch per wave
@@ -169,7 +172,8 @@ class MeshBackend:
         steady-state short-circuit live in the returned closure, so two
         sessions driving one backend cannot thrash each other's anchors.
         """
-        jitted = jax.jit(jax.vmap(fused_select_step(fn, sampled=sampled)))
+        jitted, bound = fused_wave_program(fn, sampled=sampled)
+        bound = self._place_bound(bound, self._replicated)
         last_state = last_tokens = last_rows = None
 
         def wave(stacked, tokens, rows):
@@ -187,7 +191,7 @@ class MeshBackend:
                 # replicate (like admission handoffs) — the cost is
                 # bytes, and the per-slot selection reads only its row
                 rows = jax.device_put(rows, self._replicated)
-            out, new_state, new_rows = jitted(stacked, tokens, rows)
+            out, new_state, new_rows = jitted(bound, stacked, tokens, rows)
             last_tokens, last_state, last_rows = out, new_state, new_rows
             return out, new_state, new_rows
 
@@ -196,12 +200,26 @@ class MeshBackend:
 
     # -- prefill (donor stream) --------------------------------------------
 
+    def _place_bound(self, bound: tuple, target) -> tuple:
+        """A step's bound weights placed on ``target`` (the replicated
+        mesh sharding, or the donor device), once per (weights, target):
+        every wave and prefill then finds its weights already resident."""
+        leaves = jax.tree.leaves(bound)
+        key = (tuple(id(x) for x in leaves), target)
+        hit = self._bound_cache.get(key)
+        if hit is None:
+            # the source leaves ride along so their ids stay unique
+            hit = (leaves, jax.device_put(bound, target))
+            self._bound_cache[key] = hit
+        return hit[1]
+
     def _donor_prefill(self, tokens):
         """Single-prompt prefill pinned to the donor device (committed
-        inputs make the inner jitted prefill execute there)."""
+        inputs and weights make the inner jitted prefill execute there)."""
+        step, bound = split_bound(self.inner.prefill_fn)
         tokens = jax.device_put(jnp.asarray(tokens, jnp.int32),
                                 self._donor_sharding)
-        return self.inner.prefill_fn(tokens)
+        return step(*self._place_bound(bound, self._donor_sharding), tokens)
 
     def vmapped_prefill(self, prompts):
         """Group prefill (ONE vmapped dispatch) on the donor device —
@@ -209,11 +227,11 @@ class MeshBackend:
         occupies the mesh; ``place_rows`` hands the result over at
         install time."""
         if self._vp_jit is None:
-            inner_prefill = self.inner.prefill_fn
-            self._vp_jit = jax.jit(jax.vmap(lambda p: inner_prefill(p[None, :])))
+            self._vp_jit = vmapped_prefill(self.inner.prefill_fn)
+        jitted, bound = self._vp_jit
         prompts = jax.device_put(jnp.asarray(prompts, jnp.int32),
                                  self._donor_sharding)
-        return self._vp_jit(prompts)
+        return jitted(self._place_bound(bound, self._donor_sharding), prompts)
 
     # -- data-path delegation ----------------------------------------------
     # (identity-stable like MeteredBackend: the session's wave cache keys

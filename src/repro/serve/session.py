@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any, Iterator
 
@@ -70,7 +71,8 @@ import numpy as np
 from repro.sample import (MAX_STOP_TOKENS, SamplerRows, SamplerSpec,
                           sample_token, select_tokens, token_logprobs)
 from repro.serve.backend import (DecodeBackend, ServingBackend,
-                                 make_fused_wave)
+                                 make_fused_wave, split_bound,
+                                 vmapped_prefill)
 from repro.serve.policy import HysteresisPolicy, SectorPolicy
 from repro.serve.pool import KVPagePool
 from repro.serve.prefix import PrefixCache, PrefixLease
@@ -591,9 +593,8 @@ class ServeSession:
                 if backend_vp is not None:
                     self._vmapped_prefill = backend_vp
                 else:
-                    prefill_fn = self.backend.prefill_fn
-                    self._vmapped_prefill = jax.jit(
-                        jax.vmap(lambda p: prefill_fn(p[None, :])))
+                    jitted, bound = vmapped_prefill(self.backend.prefill_fn)
+                    self._vmapped_prefill = functools.partial(jitted, bound)
             stacked_prompts = jnp.asarray(np.stack(prompts), jnp.int32)
             logits, stacked = self._vmapped_prefill(stacked_prompts)
         if self.prefix_cache is not None:
@@ -1060,7 +1061,12 @@ class ServeSession:
         wave = self._wave_cache.get(key)
         if wave is None:
             if not self.fuse_wave:
-                wave = jax.jit(jax.vmap(fn))  # pre-fused reference wave
+                # pre-fused reference wave: logits out, weights passed as
+                # an unbatched argument
+                step, bound = split_bound(fn)
+                jitted = jax.jit(jax.vmap(lambda b, s, t: step(*b, s, t),
+                                          in_axes=(None, 0, 0)))
+                wave = functools.partial(jitted, bound)
             elif self._backend_wave_for is not None:
                 wave = self._backend_wave_for(fn, sampled=sampled)
             else:

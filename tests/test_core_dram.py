@@ -5,7 +5,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import dram
 from repro.core.timing import DEFAULT_TIMING as T

@@ -181,21 +181,24 @@ def _dispatch_formulation(qg, kp_pm, vp_pm, page_idx, length):
     vh = vp_pm.transpose(0, 3, 1, 2, 4)
     k_sel = jnp.take_along_axis(kh, pages[..., None, None], axis=2)
     v_sel = jnp.take_along_axis(vh, pages[..., None, None], axis=2)
+    K = pages.shape[-1]
     scores = jnp.einsum("bgrk,bgcpk->bgrcp", qg.astype(k_sel.dtype), k_sel,
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(jnp.float32(hd))
+    scores = scores.reshape(B, Hkv, -1, K * page)
     tok_pos = pages[..., None] * page + jnp.arange(page)
-    valid = tok_pos < length[:, None, None, None]
-    scores = jnp.where(valid[:, :, None, :, :], scores, ref.NEG_INF)
-    m = jnp.max(scores, axis=(-2, -1), keepdims=True)
+    valid = (tok_pos < length[:, None, None, None]).reshape(B, Hkv, 1, -1)
+    scores = jnp.where(valid, scores, ref.NEG_INF)
+    m = jnp.max(scores, axis=-1, keepdims=True)
     e = jnp.exp(scores - jax.lax.stop_gradient(m))
-    e = jnp.where(valid[:, :, None, :, :], e, 0.0)
-    num = jnp.einsum("bgrcp,bgcpk->bgrk", e.astype(v_sel.dtype), v_sel,
+    e = jnp.where(valid, e, 0.0)
+    num = jnp.einsum("bgrs,bgsk->bgrk", e.astype(v_sel.dtype),
+                     v_sel.reshape(B, Hkv, K * page, hd),
                      preferred_element_type=jnp.float32)
-    den = jnp.sum(e, axis=(-2, -1))[..., None]
+    den = jnp.sum(e, axis=-1)[..., None]
     out = num / jnp.maximum(den, 1e-30)
-    mass = jnp.sum(e, axis=(2, 4)) / jnp.maximum(
-        jnp.sum(e, axis=(2, 3, 4))[..., None], 1e-30)
+    mass = jnp.sum(e.reshape(B, Hkv, -1, K, page), axis=(2, 4)) / jnp.maximum(
+        jnp.sum(e, axis=(2, 3))[..., None], 1e-30)
     return out, mass
 
 
@@ -271,7 +274,8 @@ def test_default_interpret_compiled_on_tpu(monkeypatch):
 
 
 @pytest.mark.parametrize("fn", [ops.vbl_gather, ops.sectored_attention,
-                                ops.sectored_attention_paged])
+                                ops.sectored_attention_paged,
+                                ops.flash_attention])
 def test_kernel_wrappers_default_to_auto_interpret(fn):
     """Every public kernel wrapper defaults interpret=None (auto-detect),
     not a hardwired True."""
@@ -362,6 +366,25 @@ def test_fused_step_bitwise_with_dispatch(setup, prompt_len):
         np.testing.assert_array_equal(np.asarray(state_d.kv.k),
                                       np.asarray(state_f.kv.k))
         tok = jnp.argmax(ld, -1)[:, None].astype(jnp.int32)
+
+
+def test_eager_steps_compile_once(setup):
+    """Regression for the page-edge failure at prompt length PAGE + 1:
+    stepping token by token outside ``jit`` re-traced and re-compiled the
+    layer scan on every call (a fresh body closure each time), and the
+    hundreds of compiles that ``_prefilled`` piles up crashed the XLA CPU
+    compiler on the longest prompt. The step is jitted with its
+    configuration static, so a token-by-token loop compiles once."""
+    cfg, params = setup
+    state = sectored_decode.init_state(cfg, 1, 384)
+    token = jnp.zeros((1, 1), jnp.int32)
+    step = sectored_decode.sectored_decode_step
+    _, state = step(params, cfg, state, token, k_pages=8)
+    compiled = step._cache_size()
+    for _ in range(4):
+        _, state = step(params, cfg, state, token, k_pages=8)
+    assert step._cache_size() == compiled
+    assert int(state.position[0]) == 5
 
 
 @pytest.mark.slow

@@ -12,7 +12,7 @@ meter's books for arbitrary wave shapes — shared prefix groups included.
 import json
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import power
 from repro.core.timing import DEFAULT_TIMING as T

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import configs
 from repro.models import model
@@ -425,14 +425,12 @@ def test_path_decision_topk_frac_respecialises_backend(setup):
 #
 # The old example-based cases (one fixed non-contiguous grouping, two fixed
 # out-of-range ids) are generalized into properties over arbitrary
-# groupings, via tests/_hypothesis_compat (real hypothesis when installed,
-# the deterministic fallback otherwise).
+# groupings.
 
 
 def _tables_and_gids(n_slots, raw_gids, seed):
     """Deterministic (S, L, B, H, P) score tables + in-range group ids
-    derived from drawn integers (strategies stay dependency-free: the
-    compat fallback has no flatmap/composite)."""
+    derived from drawn integers."""
     rng = np.random.default_rng(seed)
     tables = rng.random((n_slots, 1, 1, 2, 4)).astype(np.float32)
     gids = np.asarray([raw_gids[i % len(raw_gids)] % n_slots

@@ -11,7 +11,7 @@ silently, elsewhere).
 import jax
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import roofline
