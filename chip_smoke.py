@@ -24,7 +24,6 @@ The last line of standard output is one JSON object naming the device.
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import pathlib
 import sys
@@ -54,15 +53,12 @@ NEW_TOKENS = 16
 # two prompt lengths (two prefill compiles); 700 tokens fill 6 pages, so
 # the 4 + 1-probe page budget really skips one of them
 PROMPT_LENS = (700, 16, 16, 700, 16, 16)
-# teacher-forced one-step agreement with the dispatch path, max |d logprob|:
-# the fused kernel reorders f32 accumulation only (bf16 logits carry
-# ~0.03 of rounding at |logit| ~ 8); int8 KV adds quantization error
-FUSED_LOGPROB_TOL = 0.05
+# teacher-forced one-step agreement with the dispatch path, max |d logprob|.
+# The fused kernel runs the dispatch path's operands and reduction axes, and
+# reads 0.0 on a v5e; int8 KV reads ~0.033 there. The fused limit sits
+# between the two, and fused_q8 is the control that must exceed it.
+FUSED_LOGPROB_TOL = 1e-3
 Q8_LOGPROB_TOL = quantized_kv.LOGPROB_TOL
-# --four-chips: every device holds one replicated copy of the weights for
-# the mesh session next to the one-device session's copy, so the depth is
-# cut to keep two copies and the KV inside one chip's 16 GB
-MESH_LAYERS = 8
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -170,14 +166,18 @@ def one_chip(cfg) -> None:
         f"fused_q8 vs dispatch max|dlogprob|={d_q8!r} (tol {Q8_LOGPROB_TOL})")
     check(d_fused <= FUSED_LOGPROB_TOL, "fused step disagrees with dispatch")
     check(d_q8 <= Q8_LOGPROB_TOL, "fused_q8 step disagrees with dispatch")
+    check(d_q8 > FUSED_LOGPROB_TOL,
+          "control: int8 KV error is within the fused limit, so the limit "
+          "cannot tell a lossy kernel from a sound one")
 
 
 def four_chips(cfg) -> None:
-    cfg = dataclasses.replace(cfg, n_layers=MESH_LAYERS)
+    # every device holds one copy of the weights: the mesh's replica on
+    # device 0 is the one-device session's buffer, and the donor device's
+    # prefill reads its own replica
     say(f"config: {cfg.name} d_model={cfg.d_model} heads={cfg.n_heads}/"
         f"{cfg.n_kv_heads} d_ff={cfg.d_ff} vocab={cfg.vocab} "
-        f"layers={cfg.n_layers} (depth cut from 32: two weight copies per "
-        f"chip)")
+        f"layers={cfg.n_layers} (published depth, no cut)")
     params = build_params(cfg)
     # Mosaic kernels are not partitioned automatically, so the mesh
     # session and its one-device reference run the dispatch flavour
@@ -242,9 +242,15 @@ def main(argv=None) -> int:
         four_chips(cfg)
     else:
         one_chip(cfg)
-    stats = dev.memory_stats() or {}
-    say(f"memory: peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
-        f"bytes_limit={stats.get('bytes_limit')}")
+    peaks = []
+    for d in devices[:want]:
+        stats = d.memory_stats() or {}
+        peaks.append(stats.get("peak_bytes_in_use") or 0)
+        say(f"memory: device {d.id} peak_bytes_in_use={peaks[-1]} "
+            f"bytes_limit={stats.get('bytes_limit')}")
+    check(all(peaks), "a device reported no peak_bytes_in_use")
+    say(f"memory: largest peak_bytes_in_use={max(peaks)} over {want} "
+        f"device(s)")
     say(f"time: compile_s={compile_s[0]:.1f} "
         f"wall_s={time.perf_counter() - t0:.1f}")
     say(json.dumps({"ok": True, "device": {

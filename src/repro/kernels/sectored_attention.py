@@ -14,10 +14,11 @@ Two entry points share the steering machinery:
   ((B, Hkv, P, page, hd) KV) asserted **bitwise** against
   ``kernels/ref.py:sectored_attention_ref`` in tier-1.
 * :func:`sectored_attention_paged` — the serving kernel over the runtime's
-  page-major cache view ((B, P, page, Hkv, hd), a free reshape of the
-  (B, S, Hkv, hd) decode cache). Its KV block is one page for every kv
-  head, (page, Hkv, hd) — the last two dims full, as the TPU tiling rule
-  wants — and the kernel picks head h's rows in VMEM. Its unquantized
+  page-major cache view ((B, P, page, Hkv * hd), a reshape of the
+  (B, S, Hkv, hd) decode cache that the compiled step gets without a
+  copy). Its KV block is one head's rows of one page, (page, hd): lane
+  block h of the page, a shape the TPU tiling rule allows, so each grid
+  step moves exactly the sector it attends over. Its unquantized
   arithmetic mirrors ``runtime/sectored_decode.py:sectored_attend``
   operand-for-operand (bf16 matmul operands, f32 accumulation, identical
   mask/softmax/mass formulation), so the fused serving path is bit-exact
@@ -132,16 +133,6 @@ def _global_softmax_attend(s_scr, valid_scr, v_scr, *, page: int):
     return num / den, mass / jnp.maximum(jnp.sum(e), 1e-30)
 
 
-def _head_rows(block, h):
-    """Head ``h``'s (page, hd) rows of a full-head (page, Hkv, hd) block,
-    as f32. A one-hot select + sum over the head axis: exact (one
-    non-zero term), and unlike a dynamic index on the second-minor axis
-    it lowers under Mosaic."""
-    full = block.astype(jnp.float32)
-    head = jax.lax.broadcasted_iota(jnp.int32, full.shape, 1)
-    return jnp.sum(jnp.where(head == h, full, 0.0), axis=1)
-
-
 def _ref_kernel(pages_ref, length_ref,  # scalar prefetch
                 q_ref, k_ref, v_ref,  # VMEM blocks
                 out_ref,  # VMEM output block
@@ -189,19 +180,14 @@ def _paged_kernel(pages_ref, length_ref, *refs, page_size: int,
     page_id = pages_ref[b, 0 if shared_pages else h, i]
 
     q = q_ref[0, 0]  # (rep, hd) — bf16 operand, like the dispatch path
-    # the block holds every kv head of the selected page: bf16 packs head
-    # pairs into one word, so a single head is not a DMA-able slice
-    k = _head_rows(k_ref[0, 0], h)  # (page, hd) f32, exact
-    v = _head_rows(v_ref[0, 0], h)
+    k = k_ref[0, 0]  # (page, hd): head h's rows of the selected page
+    v = v_ref[0, 0]
     if quantized:
         # dequant in the f32 accumulate: the sector's payload arrived as
         # int8 (half the burst bytes) with its one per-(page, head) scale
         q = q.astype(jnp.float32)
-        k = k * ks_ref[b, page_id, h]
-        v = v * vs_ref[b, page_id, h]
-    else:
-        k = k.astype(k_ref.dtype)
-        v = v.astype(v_ref.dtype)
+        k = k.astype(jnp.float32) * ks_ref[b, h, i]
+        v = v.astype(jnp.float32) * vs_ref[b, h, i]
     s = jnp.einsum("rk,pk->rp", q, k, preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(q.shape[-1]))
     valid = _vbl_window(page_id, length_ref, b, s.shape, page_size=page_size)
@@ -281,28 +267,33 @@ def _sectored_attention_paged(q, k_pages, v_pages, page_idx, length,
     _, P, page, _, _ = k_pages.shape
     K = page_idx.shape[-1]
     quantized = k_scale is not None
+    # a page's lanes hold (head, dim): head h's rows are lane block h, a
+    # (page, hd) block the tiling rule allows, so each step DMAs exactly
+    # one head's sector
+    k_rows = k_pages.reshape(B, P, page, Hkv * hd)
+    v_rows = v_pages.reshape(B, P, page, Hkv * hd)
 
     def kv_map(b, h, i, pages, *_):
-        return (b, pages[b, 0 if shared else h, i], 0, 0, 0)
+        return (b, pages[b, 0 if shared else h, i], 0, h)
 
     def head_map(b, h, i, *_):
         return (b, h, 0, 0)
 
     prefetch = [page_idx, length]
     if quantized:
-        # per-(sequence, page, head) scales ride in SMEM next to the page
-        # table: one scalar per fetched sector, read at the page's step
-        prefetch += [k_scale, v_scale]
+        # the selected sectors' scales ride in SMEM next to the page
+        # table, (B, Hkv, K): one scalar per fetched (page, head), so SMEM
+        # grows with the budget K, not with the context
+        sel = jnp.broadcast_to(page_idx, (B, Hkv, K))
+        prefetch += [jnp.take_along_axis(x.transpose(0, 2, 1), sel, axis=2)
+                     for x in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(B, Hkv, K),
         in_specs=[
             pl.BlockSpec((1, 1, rep, hd), head_map),
-            # last two block dims are the full (Hkv, hd): the page's rows
-            # for every kv head, one contiguous DMA of the free
-            # (B, P, page, Hkv, hd) view of the decode cache
-            pl.BlockSpec((1, 1, page, Hkv, hd), kv_map),
-            pl.BlockSpec((1, 1, page, Hkv, hd), kv_map),
+            pl.BlockSpec((1, 1, page, hd), kv_map),
+            pl.BlockSpec((1, 1, page, hd), kv_map),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, rep, hd), head_map),
@@ -320,7 +311,7 @@ def _sectored_attention_paged(q, k_pages, v_pages, page_idx, length,
         out_shape=(jax.ShapeDtypeStruct((B, Hkv, rep, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hkv, 1, K), jnp.float32)),
         interpret=interpret,
-    )(*prefetch, q, k_pages, v_pages)
+    )(*prefetch, q, k_rows, v_rows)
     return out, mass.reshape(B, Hkv, K)
 
 
@@ -331,7 +322,7 @@ def sectored_attention_paged(q, k_pages, v_pages, page_idx, length, *,
 
     q (B,Hkv,rep,hd) — the runtime's grouped query (bf16 in serving);
     k_pages/v_pages (B,P,page,Hkv,hd) — ``cache.k.reshape(B, -1, page,
-    Hkv, hd)``, a FREE reshape of the decode cache (no copy);
+    Hkv, hd)``, a free reshape of the decode cache;
     page_idx (B,Hkv,K) or (B,1,K) int32; length (B,) int32 count of valid
     tokens **including** the token appended this step (the runtime passes
     ``cache.length + 1``).
@@ -347,9 +338,10 @@ def sectored_attention_paged(q, k_pages, v_pages, page_idx, length, *,
     identical (the tier-1 oracle).
 
     Quantized: ``k_pages``/``v_pages`` are int8 with per-(sequence, page,
-    kv-head) scales ``k_scale``/``v_scale`` (B,P,Hkv) f32, fetched through
-    the same scalar-prefetched steering and dequantized in the kernel's
-    f32 accumulate. Tolerance-gated, not bit-gated.
+    kv-head) scales ``k_scale``/``v_scale`` (B,P,Hkv) f32. The selected
+    pages' scales are gathered to (B,Hkv,K) and scalar-prefetched beside
+    the page table, and each page is dequantized in the kernel's f32
+    accumulate. Tolerance-gated, not bit-gated.
     """
     shared = _check_page_idx(page_idx, k_pages.shape[3])
     if (k_scale is None) != (v_scale is None):

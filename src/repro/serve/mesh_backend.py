@@ -173,7 +173,7 @@ class MeshBackend:
         sessions driving one backend cannot thrash each other's anchors.
         """
         jitted, bound = fused_wave_program(fn, sampled=sampled)
-        bound = self._place_bound(bound, self._replicated)
+        bound = self._place_bound(bound)
         last_state = last_tokens = last_rows = None
 
         def wave(stacked, tokens, rows):
@@ -200,18 +200,39 @@ class MeshBackend:
 
     # -- prefill (donor stream) --------------------------------------------
 
-    def _place_bound(self, bound: tuple, target) -> tuple:
-        """A step's bound weights placed on ``target`` (the replicated
-        mesh sharding, or the donor device), once per (weights, target):
-        every wave and prefill then finds its weights already resident."""
+    def _place_bound(self, bound: tuple, *, donor: bool = False) -> tuple:
+        """A step's bound weights replicated over the mesh, once per
+        weights, so every wave and prefill finds them resident. Each
+        device holds ONE copy: a weight already on a mesh device keeps
+        its buffer as that device's replica, and the donor prefill
+        (``donor=True``) reads the donor device's replica instead of a
+        copy of its own."""
         leaves = jax.tree.leaves(bound)
-        key = (tuple(id(x) for x in leaves), target)
+        key = tuple(id(x) for x in leaves)
         hit = self._bound_cache.get(key)
         if hit is None:
+            placed = jax.tree.map(self._replicate, bound)
             # the source leaves ride along so their ids stay unique
-            hit = (leaves, jax.device_put(bound, target))
+            hit = (leaves, placed, jax.tree.map(self._donor_replica, placed))
             self._bound_cache[key] = hit
-        return hit[1]
+        return hit[2] if donor else hit[1]
+
+    def _replicate(self, x):
+        devices = list(self.mesh.devices.flat)
+        if isinstance(x, jax.Array) and len(x.devices()) == 1:
+            (home,) = x.devices()
+            if home in devices:
+                return jax.make_array_from_single_device_arrays(
+                    x.shape, self._replicated,
+                    [x if d == home else jax.device_put(x, d)
+                     for d in devices])
+        return jax.device_put(x, self._replicated)
+
+    def _donor_replica(self, x):
+        """The donor device's shard of a replicated array: a view of its
+        buffer, not a copy."""
+        return next(s.data for s in x.addressable_shards
+                    if s.device == self._donor)
 
     def _donor_prefill(self, tokens):
         """Single-prompt prefill pinned to the donor device (committed
@@ -219,7 +240,7 @@ class MeshBackend:
         step, bound = split_bound(self.inner.prefill_fn)
         tokens = jax.device_put(jnp.asarray(tokens, jnp.int32),
                                 self._donor_sharding)
-        return step(*self._place_bound(bound, self._donor_sharding), tokens)
+        return step(*self._place_bound(bound, donor=True), tokens)
 
     def vmapped_prefill(self, prompts):
         """Group prefill (ONE vmapped dispatch) on the donor device —
@@ -231,7 +252,7 @@ class MeshBackend:
         jitted, bound = self._vp_jit
         prompts = jax.device_put(jnp.asarray(prompts, jnp.int32),
                                  self._donor_sharding)
-        return jitted(self._place_bound(bound, self._donor_sharding), prompts)
+        return jitted(self._place_bound(bound, donor=True), prompts)
 
     # -- data-path delegation ----------------------------------------------
     # (identity-stable like MeteredBackend: the session's wave cache keys

@@ -291,6 +291,9 @@ class PrefixCache:
         pages = self.pages_for(len(key))
         if not self._make_room(pages):
             return False
+        # eviction may have pruned the nodes the first walk ended on:
+        # walk again so the entry hangs off the live tree
+        node, depth, partial, child = self._descend(key)
         if partial:
             # split child's edge at the divergence point
             node = self._split(node, child, partial)

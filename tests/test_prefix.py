@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro import configs
 from repro.models import model
@@ -87,6 +87,8 @@ def test_match_length_monotone_in_query_prefix(prompts, probe):
 @given(st.lists(st.tuples(st.lists(TOK, min_size=1, max_size=10),
                           st.booleans()),
                 min_size=1, max_size=10))
+# the insert that evicted the node its own walk ended on
+@example(entries=[([0], False), ([1], False), ([0, 0], False)])
 def test_never_evicts_referenced_entries(entries):
     """Refcount > 0 pins an entry through arbitrary admission pressure:
     a 2-page cache under a stream of inserts evicts constantly, but every

@@ -171,6 +171,32 @@ def test_mesh_backend_is_transparent_decorator(setup):
     assert MeshBackend(MeteredBackend(inner), mesh).shard_pages is True
 
 
+@pytest.mark.parametrize("spec", ["1", "4x2"])
+def test_weights_held_once_per_device(setup, spec, request):
+    """Waves and donor prefill share ONE copy of the weights per device:
+    the replica on the device the weights already live on is their own
+    buffer, and the donor prefill reads the donor's replica."""
+    if spec != "1":
+        request.getfixturevalue("eight_devices")
+    cfg, params = setup
+    inner = sectored_decode.make_serving_fns(cfg, params=params, seq_len=48)
+    backend = MeshBackend(inner, mesh_mod.make_serving_mesh(spec))
+    wave = backend._place_bound((params,))
+    donor = backend._place_bound((params,), donor=True)
+    for p, w, d in zip(jax.tree.leaves(params), jax.tree.leaves(wave),
+                       jax.tree.leaves(donor)):
+        assert w.is_fully_replicated
+        assert len(w.sharding.device_set) == backend.mesh.devices.size
+        home = {s.device: s.data.unsafe_buffer_pointer()
+                for s in w.addressable_shards}
+        (p_dev,) = p.devices()
+        assert home[p_dev] == p.unsafe_buffer_pointer()
+        assert d.devices() == {backend.donor_device}
+        assert d.unsafe_buffer_pointer() == home[backend.donor_device]
+    # placed once: a second wave or prefill finds the same arrays
+    assert backend._place_bound((params,)) is wave
+
+
 # -- needs 8 devices (CI multi-device job) -----------------------------------
 
 

@@ -23,10 +23,11 @@ from jax.sharding import SingleDeviceSharding
 from repro import configs
 from repro.kernels import backend as kbackend
 from repro.kernels import ops
+from repro.launch.serve import build_backend
 from repro.models import model
 from repro.runtime import sectored_decode
 from repro.sample import SamplerRows
-from repro.serve.backend import fused_wave_program
+from repro.serve.backend import fused_wave_program, vmapped_prefill
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
 SLOTS = 4
@@ -83,16 +84,24 @@ def _kernel_args(yi, *, quantized, shared, batch, pages, k):
     return args, scales
 
 
+# (pages, budget): 8k tokens at 1/8 plus the probe page, and a 128k
+# context, where the kernel's SMEM (page table, selected scales) must grow
+# with the budget and not with the context
+CONTEXTS = {"8k": (64, 9), "128k": (1024, 129)}
+
+
+@pytest.mark.parametrize("context", sorted(CONTEXTS))
 @pytest.mark.parametrize("vmapped", [False, True], ids=["plain", "vmap"])
 @pytest.mark.parametrize("shared", [False, True], ids=["per_head", "shared"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_paged_kernel_compiles_at_yi6b_widths(one_chip, quantized, shared,
-                                              vmapped):
+                                              vmapped, context):
     """``sectored_attention_paged`` at yi-6b's Hkv 4, rep 8, hd 128, page
     128 lowers through Mosaic, alone and under the session's slot vmap."""
     yi = configs.get("yi-6b")
+    pages, k = CONTEXTS[context]
     args, scales = _kernel_args(yi, quantized=quantized, shared=shared,
-                                batch=1 if vmapped else 2, pages=64, k=9)
+                                batch=1 if vmapped else 2, pages=pages, k=k)
 
     def call(q, kp, vp, idx, length, *sc):
         return ops.sectored_attention_paged(
@@ -156,6 +165,35 @@ def test_decode_wave_compiles_at_full_widths(one_chip, kernel,
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert mem.argument_size_in_bytes >= weights  # weights are arguments
     assert _bytes(mem) < HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["wave", "prefill"])
+@pytest.mark.parametrize("true_sectored", [False, True],
+                         ids=["dense", "sectored"])
+def test_serving_programs_take_weights_as_arguments(true_sectored, program):
+    """Every backend ``build_session`` can build hands its weights to the
+    session's wave and vmapped prefill as an argument (lowered on the
+    host at toy widths; no chip needed)."""
+    cfg = configs.get("yi-6b").reduced(n_layers=2, d_model=64, n_heads=4,
+                                       n_kv_heads=2, d_ff=128, vocab=128,
+                                       head_dim=16)
+    params = model.init_params(cfg, jax.random.key(0))
+    backend = build_backend(cfg, params, true_sectored=true_sectored,
+                            seq_len=48)
+    prompts = jnp.zeros((2, 6), jnp.int32)
+    if program == "prefill":
+        jitted, bound = vmapped_prefill(backend.prefill_fn)
+        lowered = jitted.lower(bound, prompts)
+    else:
+        _, stacked = jax.vmap(lambda p: backend.prefill_fn(p[None, :]))(
+            prompts)
+        jitted, bound = fused_wave_program(backend.sectored_fn)
+        lowered = jitted.lower(bound, stacked,
+                               jnp.zeros((2, 1, 1), jnp.int32),
+                               SamplerRows.init(2))
+    assert bound, "the step binds no weights to pass as an argument"
+    assert not _weight_sized_constants(lowered.as_text(),
+                                       cfg.d_model * cfg.d_model)
 
 
 def test_constant_scan_catches_closed_over_weights(one_chip):
