@@ -230,6 +230,7 @@ def _sectored_attention(q, k_pages, v_pages, page_idx, length, shared: bool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hd), jnp.float32),
         interpret=interpret,
+        name="sectored_attention",
     )(page_idx, length, q, k_pages, v_pages)
 
 
@@ -311,6 +312,7 @@ def _sectored_attention_paged(q, k_pages, v_pages, page_idx, length,
         out_shape=(jax.ShapeDtypeStruct((B, Hkv, rep, hd), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hkv, 1, K), jnp.float32)),
         interpret=interpret,
+        name="sectored_attention_paged",
     )(*prefetch, q, k_rows, v_rows)
     return out, mass.reshape(B, Hkv, K)
 

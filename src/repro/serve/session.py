@@ -54,6 +54,13 @@ capacity"):
   their state, and counter-keyed RNG restarts sampling at position
   ``len(generated)`` — so on the exact decode path a preempted
   request's stream is bit-identical to an uncontended run.
+
+Each phase of a step's host work runs inside a
+``jax.profiler.TraceAnnotation`` named ``session.*`` (schedule, prefill,
+install, merge_demands, wave_dispatch, wave_sync, emit). The spans exist
+only in a running profiler's trace, on the clock its device events are
+aligned to, and cost about a microsecond each without one; they change
+nothing that is dispatched or computed (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -67,6 +74,7 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.sample import (MAX_STOP_TOKENS, SamplerRows, SamplerSpec,
                           sample_token, select_tokens, token_logprobs)
@@ -376,7 +384,8 @@ class ServeSession:
     def _zero_stats() -> dict[str, int]:
         return dict(decode_steps=0, sectored_steps=0, completed=0, waves=0,
                     sectored_waves=0, merged_slots=0, overlapped_prefills=0,
-                    prefill_calls=0, preemptions=0, eos_stops=0)
+                    prefill_calls=0, prefill_tokens=0, preemptions=0,
+                    eos_stops=0)
 
     def reset_stats(self) -> None:
         self.stats = self._zero_stats()
@@ -508,9 +517,11 @@ class ServeSession:
             lease = self.prefix_cache.acquire(prompt,
                                               max_match=len(prompt) - 1)
         if lease is None:
+            self.stats["prefill_tokens"] += len(prompt)
             logits, state = self.backend.prefill_fn(prompt[None, :])
             return logits, state, None
         m = lease.matched_tokens
+        self.stats["prefill_tokens"] += len(prompt) - m
         seed = self._state_prefix(lease.entry.state, m)
         logits, state = self._suffix_prefill(seed, prompt[None, m:])
         return logits, state, lease
@@ -518,24 +529,38 @@ class ServeSession:
     def prefill_one(self, handle: StreamHandle):
         """Blocking single-prompt prefill; returns (first_token, state)."""
         prompt = self.effective_prompt(handle)
-        logits, state, lease = self._prefill_states(handle, prompt)
-        handle._lease = lease
-        if lease is not None:
-            self._leased_handles.add(handle)
-        self.stats["prefill_calls"] += 1
-        if self.prefix_cache is not None and not handle._tokens:
-            # fresh admissions (cold AND warm) insert their full-prompt
-            # post-prefill state — warm inserts deepen the shared prefix;
-            # dedupe just refreshes recency
-            self.prefix_cache.insert(prompt, state)
-        if self.meter is not None:
-            self.meter.record_prefill(
-                handle.rid, len(prompt), overlapped=self.wave_in_flight,
-                resumed=bool(handle._tokens),
-                cached_tokens=lease.matched_tokens if lease else 0)
-        tok = self._first_token(handle, logits[0])
-        handle._first_logp = self._logp_of(logits[0], tok)
+        with TraceAnnotation("session.prefill", rid=handle.rid) as span:
+            logits, state, lease = self._prefill_states(handle, prompt)
+            handle._lease = lease
+            if lease is not None:
+                self._leased_handles.add(handle)
+            span.set_metadata(**self._prefill_meta([handle], [prompt]))
+            self.stats["prefill_calls"] += 1
+            if self.prefix_cache is not None and not handle._tokens:
+                # fresh admissions (cold AND warm) insert their full-prompt
+                # post-prefill state — warm inserts deepen the shared
+                # prefix; dedupe just refreshes recency
+                self.prefix_cache.insert(prompt, state)
+            if self.meter is not None:
+                self.meter.record_prefill(
+                    handle.rid, len(prompt), overlapped=self.wave_in_flight,
+                    resumed=bool(handle._tokens),
+                    cached_tokens=lease.matched_tokens if lease else 0)
+            tok = self._first_token(handle, logits[0])
+            handle._first_logp = self._logp_of(logits[0], tok)
         return tok, state
+
+    @staticmethod
+    def _prefill_meta(handles: list[StreamHandle], prompts: list) -> dict:
+        """Profiler metadata of a ``session.prefill`` span, once the
+        leases are known: the prompt tokens scanned and the mode (a
+        resumed request re-prefills cold; ``warm`` seeds from the prefix
+        cache)."""
+        matched = sum(h._lease.matched_tokens for h in handles
+                      if h._lease is not None)
+        mode = ("resume" if any(h._tokens for h in handles) else
+                "warm" if matched else "cold")
+        return dict(tokens=sum(len(p) for p in prompts) - matched, mode=mode)
 
     @staticmethod
     def _logp_of(logits_row, tok: int) -> float:
@@ -573,6 +598,14 @@ class ServeSession:
         if len(lengths) != 1:
             raise ValueError(f"prefill_group needs equal prompt lengths, "
                              f"got {sorted(lengths)}")
+        rids = ",".join(str(h.rid) for h in handles)
+        with TraceAnnotation("session.prefill", rids=rids) as span:
+            group = self._prefill_group(handles, prompts)
+            span.set_metadata(**self._prefill_meta(handles, prompts))
+        return group
+
+    def _prefill_group(self, handles: list[StreamHandle],
+                       prompts: list) -> PrefillGroup:
         self.stats["prefill_calls"] += 1
         if len(handles) == 1:
             # the one branch that can go warm: a prefix-cache hit seeds
@@ -596,6 +629,7 @@ class ServeSession:
                     jitted, bound = vmapped_prefill(self.backend.prefill_fn)
                     self._vmapped_prefill = functools.partial(jitted, bound)
             stacked_prompts = jnp.asarray(np.stack(prompts), jnp.int32)
+            self.stats["prefill_tokens"] += sum(len(p) for p in prompts)
             logits, stacked = self._vmapped_prefill(stacked_prompts)
         if self.prefix_cache is not None:
             for j, (h, p) in enumerate(zip(handles, prompts)):
@@ -654,6 +688,11 @@ class ServeSession:
                 state) -> None:
         """Place one prefilled request into a slot and emit its first
         token (the FIFO admission path)."""
+        with TraceAnnotation("session.install", rid=handle.rid, slot=slot):
+            self._install(slot, handle, first_token, state)
+
+    def _install(self, slot: int, handle: StreamHandle, first_token: int,
+                 state) -> None:
         if self.vectorized:
             self._prepare_wave_buffer(
                 state_signature(state),
@@ -681,6 +720,13 @@ class ServeSession:
         if len(slots) != len(group):
             raise ValueError(f"{len(group)} prefilled requests for "
                              f"{len(slots)} slots")
+        with TraceAnnotation(
+                "session.install",
+                rids=",".join(str(h.rid) for h in group.handles),
+                slots=",".join(str(s) for s in slots)):
+            self._install_group(slots, group)
+
+    def _install_group(self, slots: list[int], group: PrefillGroup) -> None:
         if self.vectorized:
             self._prepare_wave_buffer(
                 group.sig,
@@ -1084,7 +1130,8 @@ class ServeSession:
         """Admit + one decode wave. Returns tokens produced."""
         if self.obs is not None:
             self.obs.advance()  # the virtual step clock every span keys on
-        self.scheduler.schedule(self)
+        with TraceAnnotation("session.schedule"):
+            self.scheduler.schedule(self)
         active = self.active_slots()
         if not active:
             return 0
@@ -1093,7 +1140,8 @@ class ServeSession:
                             and self.backend.supports_sectored)
         if (use_sectored and decision.merge_demands
                 and self.backend.demand_merge_fn is not None):
-            self._merge_demands(active)
+            with TraceAnnotation("session.merge_demands"):
+                self._merge_demands(active)
         fn = (self.backend.sectored_fn_for(decision.topk_frac)
               if use_sectored else self.backend.decode_fn)
         sampled = self._wave_sampled(active)
@@ -1104,65 +1152,73 @@ class ServeSession:
         if self.vectorized:
             # dispatch the wave (async), let the scheduler overlap prefill
             # work with it, then block on the results
-            wave, out = self._launch_vectorized(active, fn, sampled)
+            with TraceAnnotation("session.wave_dispatch", active=len(active)):
+                wave, out = self._launch_vectorized(active, fn, sampled)
             self.wave_in_flight = True
             try:
                 self.scheduler.overlap(self)
             finally:
                 self.wave_in_flight = False
-            if getattr(wave, "returns_tokens", False):
-                # fused pipeline (the default): tokens were selected
-                # on-device — per-slot first-max argmax or the sampling
-                # kernel, bit-identical to the reference paths below; the
-                # per-token logprob rode out in the sampler rows
-                next_tok = np.asarray(out).reshape(self.max_batch, -1)[:, 0]
-                self._token_feedback_np = next_tok
-                logps = np.asarray(self._sampler_rows.logp)
-            elif sampled:
-                # pre-fused reference (fuse_wave=False): one extra jitted
-                # dispatch applies the SAME per-slot selection kernel to
-                # the wave's logits, advancing the RNG counters exactly
-                # like the fused executable does
-                toks, self._sampler_rows = select_tokens(
-                    out, self._sampler_rows)
-                next_tok = np.asarray(toks).reshape(self.max_batch, -1)[:, 0]
-                logps = np.asarray(self._sampler_rows.logp)
-            else:
-                # greedy pre-fused wave: the literal pre-fusion baseline
-                # (host argmax over the pulled logits) — the honest
-                # denominator of the benchmark's fused_speedup. Sampler
-                # counters need no advance here: greedy draws never read
-                # them, and a later-admitted stochastic request gets its
-                # counter scattered fresh at install
-                next_tok = np.asarray(jnp.argmax(out, axis=-1)).reshape(
-                    self.max_batch, -1)[:, 0]
-                logps = np.asarray(token_logprobs(
-                    out, jnp.asarray(next_tok, jnp.int32)))
+            with TraceAnnotation("session.wave_sync"):
+                next_tok, logps = self._collect_wave(wave, out, sampled)
         else:
-            next_tok, logps = self._run_looped(active, fn)
+            with TraceAnnotation("session.wave_dispatch", active=len(active)):
+                next_tok, logps = self._run_looped(active, fn)
             self.scheduler.overlap(self)
         # wall_s is snapped first so it brackets just dispatch + device
         # drain + overlap — not the telemetry table pull below or the emit
         # bookkeeping; wave info is captured before _emit_wave (finished
         # slots vacate) and the meter is driven after it
         wall_s = time.perf_counter() - t0 if self.meter is not None else 0.0
-        wave_info = (self._meter_wave_info(active, decision, use_sectored)
-                     if self.meter is not None else None)
-        # (slot, rid) pairs captured before _emit_wave vacates finished slots
-        active_rids = ([(s, self.slots[s].rid) for s in active]
-                       if self.obs is not None else None)
-        produced = self._emit_wave(active, next_tok, logps, use_sectored)
-        if wave_info is not None:
-            self.meter.record_wave(wall_s=wall_s, **wave_info)
-        if self.obs is not None:
-            energy = (self.meter.recorder.window(1)[-1]
-                      if self.meter is not None else None)
-            timeline = (self.meter.last_timeline
-                        if self.meter is not None else None)
-            self.obs.on_wave(active_rids=active_rids, produced=produced,
-                             sectored=use_sectored, energy=energy,
-                             timeline=timeline)
+        with TraceAnnotation("session.emit"):
+            wave_info = (self._meter_wave_info(active, decision, use_sectored)
+                         if self.meter is not None else None)
+            # (slot, rid) pairs captured before _emit_wave vacates
+            # finished slots
+            active_rids = ([(s, self.slots[s].rid) for s in active]
+                           if self.obs is not None else None)
+            produced = self._emit_wave(active, next_tok, logps, use_sectored)
+            if wave_info is not None:
+                self.meter.record_wave(wall_s=wall_s, **wave_info)
+            if self.obs is not None:
+                energy = (self.meter.recorder.window(1)[-1]
+                          if self.meter is not None else None)
+                timeline = (self.meter.last_timeline
+                            if self.meter is not None else None)
+                self.obs.on_wave(active_rids=active_rids, produced=produced,
+                                 sectored=use_sectored, energy=energy,
+                                 timeline=timeline)
         return produced
+
+    def _collect_wave(self, wave, out, sampled: bool
+                      ) -> tuple[np.ndarray, np.ndarray]:
+        """Block on a dispatched wave: its tokens and their logprobs on
+        the host, (max_batch,) each."""
+        if getattr(wave, "returns_tokens", False):
+            # fused pipeline (the default): tokens were selected
+            # on-device — per-slot first-max argmax or the sampling
+            # kernel, bit-identical to the reference paths below; the
+            # per-token logprob rode out in the sampler rows
+            next_tok = np.asarray(out).reshape(self.max_batch, -1)[:, 0]
+            self._token_feedback_np = next_tok
+            return next_tok, np.asarray(self._sampler_rows.logp)
+        if sampled:
+            # pre-fused reference (fuse_wave=False): one extra jitted
+            # dispatch applies the SAME per-slot selection kernel to
+            # the wave's logits, advancing the RNG counters exactly
+            # like the fused executable does
+            toks, self._sampler_rows = select_tokens(out, self._sampler_rows)
+            next_tok = np.asarray(toks).reshape(self.max_batch, -1)[:, 0]
+            return next_tok, np.asarray(self._sampler_rows.logp)
+        # greedy pre-fused wave: the literal pre-fusion baseline (host
+        # argmax over the pulled logits) — the honest denominator of the
+        # benchmark's fused_speedup. Sampler counters need no advance
+        # here: greedy draws never read them, and a later-admitted
+        # stochastic request gets its counter scattered fresh at install
+        next_tok = np.asarray(jnp.argmax(out, axis=-1)).reshape(
+            self.max_batch, -1)[:, 0]
+        return next_tok, np.asarray(token_logprobs(
+            out, jnp.asarray(next_tok, jnp.int32)))
 
     def _meter_wave_info(self, active: list[int], decision,
                          use_sectored: bool) -> dict:
