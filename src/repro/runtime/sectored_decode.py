@@ -94,11 +94,34 @@ def init_state(cfg, batch, seq_len, dtype=jnp.bfloat16) -> SectoredState:
                          position=jnp.zeros((batch,), jnp.int32))
 
 
-def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
-                    probe: bool = False, kernel: str = "dispatch"):
+def append_row(buf, layer, length, row):
+    """``buf`` (L, B, Spad, Hkv, hd) with ``row`` (B, 1, Hkv, hd) written
+    at ``[layer, b, length[b]]``: one row per sequence, written in place
+    into the layer loop's carry. A masked select over every row
+    gives bitwise the same buffer but reads and writes the whole cache;
+    ``mode="drop"`` writes nothing for a ``length`` past the buffer, as
+    that select did.
+
+    One append serves one device and a mesh alike. With the page axis
+    sharded (``MeshBackend``'s wave, ``sharding.wave_state_shardings``)
+    the SPMD partitioner offsets the scatter's index by each shard's
+    first row and drops what falls outside it, so the write stays on the
+    shard that owns the row and no collective moves the cache: no select
+    is needed to keep it local (``tests/test_tpu_compile.py`` compiles
+    the sharded wave)."""
+    b = jnp.arange(row.shape[0])
+    return buf.at[layer, b, length].set(row[:, 0].astype(buf.dtype),
+                                        mode="drop")
+
+
+def sectored_attend(attn_params, cfg, x, q, k, v, length, table_l,
+                    k_pages: int, probe: bool = False,
+                    kernel: str = "dispatch"):
     """One-token decode attention over predictor-selected KV sectors.
 
-    x: (B,1,D). Returns (out, new_cache, new_table_l).
+    x: (B,1,D); ``q`` its query projection; ``k``/``v`` the layer's cache
+    (B, Spad, Hkv, hd) with this token's row already written at
+    ``length`` (B,), the pre-append lengths. Returns (out, new_table_l).
 
     ``probe=True`` widens the selection by ONE page chosen round-robin
     over the valid pages (``sector_predictor.probe_page_for``): the probed
@@ -125,20 +148,11 @@ def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
     B = x.shape[0]
     hkv, hd = cfg.n_kv_heads, cfg.head_dim_
     rep = cfg.n_heads // hkv
-    pos = cache.length[:, None]
-    q, k_new, v_new = attention.qkv(attn_params, cfg, x, pos)
     probe_page = None
     select_k = k_pages
     if probe:
-        probe_page = sector_predictor.probe_page_for(cache.length, PAGE_SIZE)
+        probe_page = sector_predictor.probe_page_for(length, PAGE_SIZE)
         select_k = k_pages + 1
-
-    # one-hot cache append (see attention.decode_attend: scatter would
-    # replicate the sharded cache under SPMD)
-    slot = jnp.arange(cache.k.shape[1])[None, :, None, None]
-    sel = slot == cache.length[:, None, None, None]
-    k = jnp.where(sel, k_new.astype(cache.k.dtype), cache.k)
-    v = jnp.where(sel, v_new.astype(cache.v.dtype), cache.v)
 
     share_heads = getattr(cfg, "sector_share_heads", False)
     if share_heads:
@@ -147,20 +161,21 @@ def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
         # head-major transpose copy and no per-head cross-shard exchange.
         shared = jnp.sum(table_l, axis=1, keepdims=True)  # (B, 1, P)
         pages1 = sector_predictor.predict_topk(
-            shared, cache.length, PAGE_SIZE, select_k,
+            shared, length, PAGE_SIZE, select_k,
             probe_page=probe_page)  # (B, 1, K)
         pages = jnp.broadcast_to(pages1, (B, hkv, select_k))
         page_idx = pages1  # singleton head axis: shared sector set
     else:
         # 1. sector bits: predictor top-k pages per (B, Hkv)
         pages = sector_predictor.predict_topk(
-            table_l, cache.length, PAGE_SIZE, select_k,
+            table_l, length, PAGE_SIZE, select_k,
             probe_page=probe_page)  # (B, Hkv, K)
         page_idx = pages
 
     if kernel != "dispatch":
-        return _attend_fused(attn_params, cfg, x, q, k, v, cache, table_l,
-                             page_idx, pages, quantized=(kernel == "fused_q8"))
+        return _attend_fused(
+            attn_params, cfg, x, q, k, v, length, table_l, page_idx, pages,
+            quantized=(kernel == "fused_q8"))
 
     if share_heads:
         kp = k.reshape(B, -1, PAGE_SIZE, hkv, hd)
@@ -198,7 +213,7 @@ def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
     scores = scores.reshape(B, hkv, rep, K * PAGE_SIZE)
     # causal/validity mask on absolute token positions
     tok_pos = pages[..., None] * PAGE_SIZE + jnp.arange(PAGE_SIZE)  # (B,H,K,p)
-    valid = (tok_pos <= cache.length[:, None, None, None]).reshape(
+    valid = (tok_pos <= length[:, None, None, None]).reshape(
         B, hkv, 1, K * PAGE_SIZE)
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
@@ -215,27 +230,25 @@ def sectored_attend(attn_params, cfg, x, cache, table_l, k_pages: int,
     # 4. SHT update: per-page attention mass (summed over q-head group)
     mass = jnp.sum(e.reshape(B, hkv, rep, K, PAGE_SIZE), axis=(2, 4)) / (
         jnp.maximum(jnp.sum(e, axis=(2, 3))[..., None], 1e-30))  # (B,Hkv,K)
-    new_table = sector_predictor.update(table_l, pages, mass)
-
-    new_cache = attention.KVCache(k=k, v=v, length=cache.length + 1)
-    return out, new_cache, new_table
+    return out, sector_predictor.update(table_l, pages, mass)
 
 
-def _attend_fused(attn_params, cfg, x, q, k, v, cache, table_l, page_idx,
+def _attend_fused(attn_params, cfg, x, q, k, v, length, table_l, page_idx,
                   pages, *, quantized: bool):
     """Steps 2–4 of :func:`sectored_attend` as ONE Pallas kernel.
 
-    ``q`` is the prologue's query projection; ``k``/``v`` the post-append
-    caches; ``page_idx`` the predictor selection as the kernel wants it
+    ``q`` is the prologue's query projection; ``k``/``v`` the layer's
+    post-append caches and ``length`` its pre-append lengths;
+    ``page_idx`` the predictor selection as the kernel wants it
     ((B,1,K) in ``sector_share_heads`` mode, (B,Hkv,K) otherwise) and
     ``pages`` the head-broadcast copy the SHT update consumes — identical
     to what the dispatch path feeds it.
 
     The page-major view is a free reshape (no copy); the kernel's
     scalar-prefetched index steering fetches exactly the selected pages
-    HBM->VMEM and masks the newest page's tail at ``cache.length + 1``
-    valid tokens (the count convention of ``kernels/sectored_attention``),
-    which is bit-for-bit the dispatch path's ``tok_pos <= cache.length``.
+    HBM->VMEM and masks the newest page's tail at ``length + 1`` valid
+    tokens (the count convention of ``kernels/sectored_attention``), which
+    is bit-for-bit the dispatch path's ``tok_pos <= length``.
     The unquantized kernel mirrors the dispatch attend op-for-op and this
     epilogue mirrors its tail, so the whole step is bitwise identical.
     """
@@ -249,16 +262,14 @@ def _attend_fused(attn_params, cfg, x, q, k, v, cache, table_l, page_idx,
         kq, k_scale = quantized_kv.quantize_pages(kp)
         vq, v_scale = quantized_kv.quantize_pages(vp)
         out, mass = ops.sectored_attention_paged(
-            qg, kq, vq, page_idx, cache.length + 1,
+            qg, kq, vq, page_idx, length + 1,
             k_scale=k_scale, v_scale=v_scale)
     else:
         out, mass = ops.sectored_attention_paged(
-            qg, kp, vp, page_idx, cache.length + 1)
+            qg, kp, vp, page_idx, length + 1)
     out = out.astype(x.dtype).reshape(B, 1, cfg.n_heads, hd)
     out = jnp.einsum("bqhk,hkd->bqd", out, attn_params["wo"])
-    new_table = sector_predictor.update(table_l, pages, mass)
-    new_cache = attention.KVCache(k=k, v=v, length=cache.length + 1)
-    return out, new_cache, new_table
+    return out, sector_predictor.update(table_l, pages, mass)
 
 
 @functools.partial(jax.jit,
@@ -285,25 +296,36 @@ def sectored_decode_step(params, cfg, state: SectoredState, token,
         hidden = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
         return model.logits_fn(params, cfg, hidden)[:, 0, :], state
 
-    def body(x, scans):
-        lp, cache, table_l = scans
+    # the stacked cache rides in the loop carry, one buffer that each
+    # layer writes its row into; scanned in and out as xs/ys it would be
+    # copied whole into a fresh stack every step
+    def body(carry, scans):
+        x, kv = carry
+        lp, table_l, layer = scans
         h = layers.rms_norm(x, lp["norm1"], cfg.norm_eps)
-        att, cache_new, table_new = sectored_attend(
-            lp["attn"], cfg, h, cache, table_l, k_pages, probe=probe,
-            kernel=kernel)
+        length = kv.length[layer]  # (B,)
+        q, k_new, v_new = attention.qkv(lp["attn"], cfg, h, length[:, None])
+        kv = attention.KVCache(k=append_row(kv.k, layer, length, k_new),
+                               v=append_row(kv.v, layer, length, v_new),
+                               length=kv.length)
+        att, table_new = sectored_attend(
+            lp["attn"], cfg, h, q, kv.k[layer], kv.v[layer], length,
+            table_l, k_pages, probe=probe, kernel=kernel)
         x = x + att
         h = layers.rms_norm(x, lp["norm2"], cfg.norm_eps)
         if cfg.moe:
             x = x + moe.moe_ffn(lp["moe"], cfg, h)
         else:
             x = x + layers.swiglu(lp["mlp"], h)
-        return x, (cache_new, table_new)
+        return (x, kv), table_new
 
-    x, (kv_new, table_new) = jax.lax.scan(
-        body, x, (params["layers"], state.kv, state.table))
+    (x, kv), table_new = jax.lax.scan(
+        body, (x, state.kv),
+        (params["layers"], state.table, jnp.arange(cfg.n_layers)))
     hidden = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = model.logits_fn(params, cfg, hidden)[:, 0, :]
-    new = SectoredState(kv=kv_new, table=table_new,
+    kv = attention.KVCache(k=kv.k, v=kv.v, length=kv.length + 1)
+    new = SectoredState(kv=kv, table=table_new,
                         position=state.position + 1)
     return logits, new
 
@@ -504,8 +526,8 @@ class SectoredKVBackend(ServingBackend):
         KV rows for positions < n depend only on those n tokens, and the
         exact-mode attend masks every row >= ``cache.length`` to exactly
         zero before the softmax max (then zeroes ``e`` again), so stale
-        rows beyond n are bit-invisible; the one-hot append overwrites
-        row n next. JAX arrays are immutable, so aliasing the donor's
+        rows beyond n are bit-invisible; the row write overwrites row n
+        next. JAX arrays are immutable, so aliasing the donor's
         k/v buffers is safe — only the length/position leaves change.
         The sector-history table is carried as-is: ``predict_topk`` at
         k = all pages returns every page in ascending order regardless

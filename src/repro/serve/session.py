@@ -504,7 +504,7 @@ class ServeSession:
         exact-mode step a cold prefill runs (``suffix_prefill``), so the
         resulting state and logits are bit-identical to the cold path
         (stale KV rows past ``m`` are masked to exact zero and
-        overwritten by the one-hot append). The match is capped at
+        overwritten by the row write). The match is capped at
         ``len(prompt) - 1`` so the suffix is never empty — the prefill
         must emit this request's own first-token logits. Resumed
         (post-preemption) re-prefills stay cold: their effective prompt

@@ -167,6 +167,134 @@ def test_decode_wave_compiles_at_full_widths(one_chip, kernel,
     assert _bytes(mem) < HBM_BYTES
 
 
+# ------------------------------------------------ the row write
+
+
+def _cache_rewrites(hlo_text: str, stacked_shape) -> list[str]:
+    """Instructions in compiled HLO that copy or select over the whole
+    stacked KV cache (in any layout, transposed or bitcast), or select over
+    one layer of it: what a step that rebuilds the cache to change one row
+    compiles to. Shapes compare as their sorted non-unit dims."""
+    def key(dims):
+        return tuple(sorted(d for d in dims if d != 1))
+
+    whole = key(stacked_shape)
+    layer = key(stacked_shape[:1] + stacked_shape[2:])
+    found = []
+    for m in re.finditer(r"%([\w.-]+) = \w+\[([0-9,]+)\]\{[^}]*\} "
+                         r"([\w-]+)\(", hlo_text):
+        name, dims, opcode = m.groups()
+        what = name if opcode == "fusion" else opcode
+        shape = key(int(d) for d in dims.split(","))
+        if ((shape == whole and ("select" in what or "copy" in what))
+                or (shape == layer and "select" in what)):
+            found.append(f"{name} {opcode} [{dims}]")
+    return found
+
+
+def _wave_state(cfg, slots, seq_len=SEQ_LEN):
+    state = jax.eval_shape(
+        lambda: sectored_decode.init_state(cfg, 1, seq_len))
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((slots,) + x.shape, x.dtype), state)
+
+
+@pytest.mark.parametrize("path", ["exact", "fused"])
+def test_decode_wave_writes_rows_in_place(one_chip, path, monkeypatch):
+    """The decode wave at yi-6b's published widths (2-layer depth cut)
+    and the doc cells' 16 slots of 9344 tokens, on their exact path and
+    the chat cell's fused one, writes each token's K/V row in place: the
+    only whole-cache rewrite left is one copy of each of K and V (the
+    wave's input into its output, which shares no buffer with it), and
+    nothing selects over the stacked cache or over a layer of it."""
+    monkeypatch.setattr(kbackend, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(configs.get("yi-6b"), n_layers=2)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.key(0))))
+    slots, seq_len = 16, 9344  # a leaf too large to stage in VMEM
+    backend = sectored_decode.SectoredKVBackend(cfg, params, seq_len=seq_len,
+                                                kernel="fused")
+    step = backend.decode_fn if path == "exact" else backend.sectored_fn
+    stacked = _wave_state(cfg, slots, seq_len)
+    jitted, bound = fused_wave_program(step)
+    compiled = jitted.lower(*_on(one_chip, (
+        bound, stacked, jax.ShapeDtypeStruct((slots, 1, 1), jnp.int32),
+        jax.eval_shape(lambda: SamplerRows.init(slots))))).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (path == "fused")
+    rewrites = _cache_rewrites(text, stacked.kv.k.shape)
+    assert len(rewrites) <= 2, rewrites
+    assert all(r.split()[1] in ("copy", "copy-done") for r in rewrites), \
+        rewrites
+
+
+def test_rewrite_scan_catches_one_hot_append(one_chip):
+    """The rewrite check is not vacuous: the append this step had — each
+    layer's cache scanned in, a one-hot select writes the row, the caches
+    stacked out — is found, and it selects over the cache."""
+    shape = (SLOTS, 2, 1, 1024, 4, 128)
+
+    def one_hot_append(k, row, length):
+        def body(_, k_l):
+            sel = (jnp.arange(k_l.shape[2])[None, None, :, None, None]
+                   == length[:, None, None, None, None])
+            return None, jnp.where(sel, row, k_l)
+
+        return jnp.moveaxis(
+            jax.lax.scan(body, None, jnp.moveaxis(k, 1, 0))[1], 0, 1)
+
+    compiled = jax.jit(one_hot_append).lower(*_on(one_chip, (
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16),
+        jax.ShapeDtypeStruct((SLOTS, 1, 1, 4, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((SLOTS,), jnp.int32)))).compile()
+    assert any("select" in r
+               for r in _cache_rewrites(compiled.as_text(), shape))
+
+
+def test_sharded_wave_writes_rows_locally(topo):
+    """On a 2x2 mesh with the page axis sharded over 'model' (the
+    ``MeshBackend`` wave), the row write partitions locally: no
+    collective carries a layer's cache or the stacked cache, whole or
+    sharded — only the selected pages the gather pulls across shards."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from repro.parallel import sharding
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    cfg = dataclasses.replace(configs.get("yi-6b"), n_layers=2)
+    params = jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.key(0)))
+    backend = sectored_decode.SectoredKVBackend(cfg, params, seq_len=SEQ_LEN)
+    stacked = _wave_state(cfg, SLOTS)
+    placed = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        stacked, sharding.wave_state_shardings(mesh, stacked))
+    assert placed.kv.k.sharding.spec[3] == "model"
+    jitted, bound = fused_wave_program(backend.sectored_fn)
+    tokens = jax.ShapeDtypeStruct(
+        (SLOTS, 1, 1), jnp.int32,
+        sharding=sharding.wave_token_sharding(mesh, (SLOTS, 1, 1)))
+    compiled = jitted.lower(
+        _on(replicated, bound), placed, tokens,
+        _on(replicated, jax.eval_shape(
+            lambda: SamplerRows.init(SLOTS)))).compile()
+    text = compiled.as_text()
+    full = stacked.kv.k.shape
+    shard = full[:3] + (full[3] // 2,) + full[4:]
+    cache_keys = {tuple(sorted(d for d in s if d != 1))
+                  for s in (full, shard, full[:1] + full[2:],
+                            shard[:1] + shard[2:])}
+    moved = re.findall(r"= (\(?[^=]*?) (?:all-gather|all-reduce|all-to-all|"
+                       r"collective-permute|reduce-scatter)(?:-start)?\(",
+                       text)
+    assert moved  # the gather of the selected pages crosses shards
+    for shapes in moved:
+        for dims in re.findall(r"\[([0-9,]+)\]", shapes):
+            key = tuple(sorted(int(d) for d in dims.split(",")
+                               if int(d) != 1))
+            assert key not in cache_keys, shapes
+
+
 @pytest.mark.parametrize("program", ["wave", "prefill"])
 @pytest.mark.parametrize("true_sectored", [False, True],
                          ids=["dense", "sectored"])
