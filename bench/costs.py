@@ -1,29 +1,30 @@
 """Operations and bytes of the sectored attention kernel and of the model.
 
 Counted from shapes, the way the algorithm needs them, not from the
-compiler. Widths come from a configuration file's sizes.
+compiler. Widths come from a configuration file's sizes; the formulas that
+depend on the architecture are its family's (``bench/families/``).
 """
 
 from __future__ import annotations
+
+from bench import families
 
 PAGE = 128  # tokens per KV page (sector)
 BF16 = 2
 F32 = 4
 
 
+def _family(sizes: dict):
+    # widths written out by hand name no family: they are dense GQA's
+    return families.of(sizes if "family" in sizes
+                       else dict(sizes, family="gqa_dense"))
+
+
 def kernel_call(sizes: dict, batch: int, selected: int
                 ) -> tuple[float, float]:
     """(flops, bytes) of one call of ``sectored_attention_paged`` for one
-    layer: every slot of the wave (``batch``), every KV head, ``selected``
-    pages each. Reads the grouped query (bf16), the K and V rows of each
-    selected page of each head; writes the f32 output and the f32 mass of
-    each selected page. Operations: the two contractions, q.k and p.v."""
-    H, G, hd = sizes["n_heads"], sizes["n_kv_heads"], sizes["head_dim"]
-    rows = batch * G * selected * PAGE
-    flops = 2 * 2 * batch * H * selected * PAGE * hd
-    read = batch * H * hd * BF16 + 2 * rows * hd * BF16
-    write = batch * H * hd * F32 + batch * G * selected * F32
-    return float(flops), float(read + write)
+    layer, over ``batch`` slots and ``selected`` pages each."""
+    return _family(sizes).kernel_call(sizes, batch, selected)
 
 
 def roofline_s(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
@@ -34,19 +35,14 @@ def roofline_s(flops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
 
 
 def matmul_params(sizes: dict) -> int:
-    """Weights a token multiplies through: every layer's projections and
-    MLP, and the head (the embedding is a lookup)."""
-    D, H, G, hd = (sizes["d_model"], sizes["n_heads"], sizes["n_kv_heads"],
-                   sizes["head_dim"])
-    layer = D * hd * (H + 2 * G) + H * hd * D + 3 * D * sizes["d_ff"]
-    return sizes["n_layers"] * layer + sizes["vocab"] * D
+    """Weights a token multiplies through."""
+    return _family(sizes).matmul_params(sizes)
 
 
 def token_flops(sizes: dict, attended: int) -> float:
     """Model FLOPs of one token that attends ``attended`` earlier tokens
-    (itself included) in every layer."""
-    attn = 2 * 2 * sizes["n_heads"] * sizes["head_dim"] * attended
-    return float(2 * matmul_params(sizes) + sizes["n_layers"] * attn)
+    (itself included)."""
+    return _family(sizes).token_flops(sizes, attended)
 
 
 def attended_decode(position: int, selected: int) -> int:

@@ -3,21 +3,16 @@
 The reference imports nothing of the program. It takes the configuration
 file's sizes, the weights that ``bench/weights.py`` makes again from the
 seed (bfloat16 values, computed in float32 here), the prompts and the tokens
-that the program served. It runs the published layer equations in
-``jax.numpy`` at ``highest`` matmul precision, layer by layer:
+that the program served. The configuration's family (``bench/families/``)
+runs its published layer equations in ``jax.numpy`` from the primitives
+here, at ``highest`` matmul precision, layer by layer, to the final hidden
+states; ``head`` turns them into logits, ``(rms(x) * g) Whead^T``.
 
-    x = embed[tokens]
-    per layer: h = rms(x) * g1; q, k, v = h Wq (+bq), h Wk (+bk), h Wv (+bv);
-               RoPE on q, k; grouped-query causal softmax attention;
-               x += attn Wo; h = rms(x) * g2; x += (silu(h Wg) * h Wu) Wd
-    logits = (rms(x) * g) Whead^T
-
-Attention is dense and causal over the whole context. So is the program's
-in every cell this reference checks: its prefill selects every page, and
-its decode waves attend every valid page (policy ``dense``, or a
-selection as wide as the context). A cell whose predictor leaves valid
-pages out computes another function, which this reference does not
-follow.
+Attention is causal over the whole context. So is the program's in every
+cell this reference checks: its prefill selects every page, and its decode
+waves attend every valid page (policy ``dense``, or a selection as wide as
+the context). A cell whose predictor leaves valid pages out computes
+another function, which this reference does not follow.
 
 Requests that share a prefix (the document of the doc cells) are run as one
 prefix pass plus one pass per request tail, so the document is computed
@@ -42,6 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import families
 
 NEG = -1e30
 Q_CHUNK = 1024
@@ -91,17 +88,6 @@ def attend(q, k, v, q_pos, k_pos, low):
     return out.reshape(Sq, H, hd)
 
 
-def project(lp, x, pos, sizes, low):
-    q = mm("sd,dhk->shk", x, lp["attn"]["wq"], low)
-    k = mm("sd,dhk->shk", x, lp["attn"]["wk"], low)
-    v = mm("sd,dhk->shk", x, lp["attn"]["wv"], low)
-    if sizes["qkv_bias"]:
-        q, k, v = (q + lp["attn"]["bq"], k + lp["attn"]["bk"],
-                   v + lp["attn"]["bv"])
-    kind, theta = sizes["rope"], float(sizes["rope_theta"])
-    return rope(q, pos, kind, theta), rope(k, pos, kind, theta), v
-
-
 def chunked_attend(q, k, v, q_pos, k_pos, low):
     """Query chunks of Q_CHUNK rows, so no (Sq, Sk) block is too large."""
     Sq = q.shape[0]
@@ -117,54 +103,14 @@ def chunked_attend(q, k, v, q_pos, k_pos, low):
     return out.reshape(n * Q_CHUNK, *q.shape[1:])[:Sq]
 
 
-def finish_layer(lp, x, att, sizes, low):
-    x = x + mm("shk,hkd->sd", att, lp["attn"]["wo"], low)
-    h = rms(x, lp["norm2"], sizes["norm_eps"])
-    g = mm("sd,df->sf", h, lp["mlp"]["w_gate"], low)
-    u = mm("sd,df->sf", h, lp["mlp"]["w_up"], low)
-    return x + mm("sf,fd->sd", jax.nn.silu(g) * u, lp["mlp"]["w_down"], low)
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1))
-def prefix_layer(frozen, low, lp, x):
-    """One layer over the shared prefix: (new x, its k, its v)."""
-    sizes = dict(frozen)
-    pos = jnp.arange(x.shape[0])
-    h = rms(x, lp["norm1"], sizes["norm_eps"])
-    q, k, v = project(lp, h, pos, sizes, low)
-    att = chunked_attend(q, k, v, pos, pos, low)
-    return finish_layer(lp, x, att, sizes, low), k, v
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def tail_layer(frozen, low, lp, x, k_pre, v_pre):
-    """One layer over the tails (n, T, D), each after the shared prefix."""
-    sizes = dict(frozen)
-    L0 = k_pre.shape[0]
-    pos = L0 + jnp.arange(x.shape[1])
-    k_pos_pre = jnp.arange(L0)
-
-    def one(xt):
-        h = rms(xt, lp["norm1"], sizes["norm_eps"])
-        q, k, v = project(lp, h, pos, sizes, low)
-        kk = jnp.concatenate([k_pre, k], axis=0)
-        vv = jnp.concatenate([v_pre, v], axis=0)
-        att = chunked_attend(q, kk, vv, pos,
-                             jnp.concatenate([k_pos_pre, pos]), low)
-        return finish_layer(lp, xt, att, sizes, low)
-
-    return jax.lax.map(one, x)
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def head(frozen, low, final_norm, lm_head, x, tokens):
+def head(eps, low, final_norm, lm_head, x, tokens):
     """x (n, T, D); tokens (m, n, T) int32 -> gaps (m, n, T) of each token
     row below the best logit, and the first choice (n, T)."""
-    sizes = dict(frozen)
 
     def one(args):
         xt, tok = args  # (T, D), (m, T)
-        logits = mm("td,vd->tv", rms(xt, final_norm, sizes["norm_eps"]),
+        logits = mm("td,vd->tv", rms(xt, final_norm, eps),
                     lm_head, low)
         best = jnp.max(logits, axis=-1)
         got = jnp.take_along_axis(logits, tok.T, axis=-1).T  # (m, T)
@@ -174,34 +120,8 @@ def head(frozen, low, final_norm, lm_head, x, tokens):
     return jnp.swapaxes(gaps, 0, 1), first
 
 
-def frozen_sizes(sizes: dict) -> tuple:
-    keys = ("d_model", "n_heads", "n_kv_heads", "head_dim", "qkv_bias",
-            "rope", "rope_theta", "norm_eps")
-    return tuple((k, sizes[k]) for k in keys)
-
-
 def f32(tree):
     return jax.tree.map(lambda a: a.astype(jnp.float32), tree)
-
-
-def forward(sizes: dict, params, prefix: np.ndarray, inputs: np.ndarray,
-            low: bool):
-    """Final hidden states (n, T, D) of the tails after the prefix."""
-    frozen = frozen_sizes(sizes)
-    emb = params["embedding"]
-    x_pre = (emb[jnp.asarray(prefix)].astype(jnp.float32)
-             if len(prefix) else None)
-    x = emb[jnp.asarray(inputs)].astype(jnp.float32)
-    G, hd = sizes["n_kv_heads"], sizes["head_dim"]
-    for layer in range(sizes["n_layers"]):
-        lp = f32(jax.tree.map(lambda a: a[layer], params["layers"]))
-        if x_pre is not None:
-            x_pre, k_pre, v_pre = prefix_layer(frozen, low, lp, x_pre)
-        else:
-            k_pre = v_pre = jnp.zeros((0, G, hd), jnp.float32)
-        x = tail_layer(frozen, low, lp, x, k_pre, v_pre)
-        del lp
-    return x
 
 
 def gaps(sizes: dict, params, prefix: np.ndarray, tails: list[np.ndarray],
@@ -214,7 +134,7 @@ def gaps(sizes: dict, params, prefix: np.ndarray, tails: list[np.ndarray],
     reference reads ``prefix + tails[i] + served[i][:-1]`` and scores
     ``served[i][j]`` at the position that produced it.
     """
-    frozen = frozen_sizes(sizes)
+    forward, eps = families.of(sizes).forward, float(sizes["norm_eps"])
     n = len(tails)
     inputs = np.zeros((n, pad_to), np.int32)
     scored = np.zeros((n, pad_to), np.int32)
@@ -231,11 +151,11 @@ def gaps(sizes: dict, params, prefix: np.ndarray, tails: list[np.ndarray],
     with jax.default_matmul_precision("highest"):
         if control:
             x8 = forward(sizes, params, prefix, inputs, True)
-            _, pick = head(frozen, True, norm, lm_head, x8, rows[0][None])
+            _, pick = head(eps, True, norm, lm_head, x8, rows[0][None])
             rows.append(pick.astype(jnp.int32))
             del x8
         x = forward(sizes, params, prefix, inputs, False)
-        gap, _ = head(frozen, False, norm, lm_head, x, jnp.stack(rows))
+        gap, _ = head(eps, False, norm, lm_head, x, jnp.stack(rows))
     gap = np.asarray(gap)
     out = []
     for row in gap:

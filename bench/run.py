@@ -5,9 +5,11 @@
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``. Its files are
 found by name: ``bench/workloads/<cell>.json`` (session settings and the
 limits of the check), ``bench/configs/<config>.json`` (the sizes, as run),
-``bench/traffic/<traffic>.json`` (the traffic's parameters, read by
-``bench/generator.py``) and ``bench/metrics/<metric>.py`` (one reader per
-per-layer metric).
+``bench/families/<family>.py`` (the code that depends on the architecture
+the sizes' ``"family"`` names: program fields, weight leaves, reference
+equations, operation counts), ``bench/traffic/<traffic>.json`` (the
+traffic's parameters, read by ``bench/generator.py``) and
+``bench/metrics/<metric>.py`` (one reader per per-layer metric).
 
 A run, in order: refuse to run without a TPU or with fewer chips than the
 cell asks for; turn on the compile cache; make the weights on the device
@@ -51,14 +53,11 @@ for p in (ROOT / "src", ROOT):
 
 import numpy as np  # noqa: E402
 
-from bench import costs, generator  # noqa: E402
+from bench import costs, families, generator  # noqa: E402
 
 BENCH = ROOT / "bench"
 OUT = ROOT / "bench_out"
 TRACE_SECONDS = 6.0
-CONFIG_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-               "d_ff", "vocab", "rope", "qkv_bias", "norm_eps",
-               "tie_embeddings", "dtype")
 
 
 class NoChip(SystemExit):
@@ -78,8 +77,7 @@ def load_cell(name: str) -> SimpleNamespace:
         if cell[key] != entry[key]:
             raise SystemExit(f"{name}: {key} is {entry[key]!r} in "
                              f"BENCHMARK.json but {cell[key]!r} in its file")
-    sizes = json.loads(
-        (BENCH / "configs" / f"{entry['config']}.json").read_text())
+    sizes = families.load(BENCH / "configs" / f"{entry['config']}.json")
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name])]
     return SimpleNamespace(name=name, cell=cell, sizes=sizes,
@@ -89,18 +87,12 @@ def load_cell(name: str) -> SimpleNamespace:
 
 
 def program_config(sizes: dict):
-    """The program's config for the sizes, checked key by key."""
+    """The program's config for the sizes: the published config of
+    ``sizes["arch"]`` with the fields that the sizes' family sets."""
     from repro import configs
     base = configs.get(sizes["arch"])
-    cfg = dataclasses.replace(
-        base, name=f"{base.name}-bench",
-        **{k: sizes[k] for k in CONFIG_KEYS if k != "head_dim"},
-        head_dim=sizes["head_dim"])
-    if cfg.head_dim_ != sizes["head_dim"] or float(sizes["rope_theta"]) \
-            != 10000.0:
-        raise SystemExit("the configuration file asks for sizes the "
-                         "program cannot run")
-    return cfg
+    return dataclasses.replace(base, name=f"{base.name}-bench",
+                               **families.of(sizes).program_fields(sizes))
 
 
 def require_chips(chips: int) -> None:

@@ -12,12 +12,17 @@ one chip that serves no batch mean has neither a dropped half of a batch nor
 an exchange between chips to leave out.
 """
 
+import json
+
+import jax
 import jax.numpy as jnp
 import pytest
 
+from bench import run
 from bench.tests import tiny
 
-CELLS = ["yi6b-doc8k-dense", "chatglm3-doc8k-dense", "yi6b-chat-short"]
+CELLS = [w["name"] for w in json.loads(
+    (run.ROOT / "BENCHMARK.json").read_text())["workloads"]]
 
 
 def _readings(result):
@@ -45,15 +50,17 @@ def test_float8_control_reads_above_the_program(cell):
 
 def _wrap_wave(session, change):
     """Plant ``change(stacked_in, out, stacked_out)`` under every wave the
-    session builds."""
+    session builds. ``stacked_in`` is a copy of the wave's input taken
+    before the wave runs, so it outlives a wave that donates its input."""
     build = session._wave_for
 
     def wave_for(fn, sampled=False):
         wave = build(fn, sampled)
 
         def faulty(stacked, tokens, rows):
+            before = jax.tree.map(jnp.copy, stacked)
             out, new, rows = wave(stacked, tokens, rows)
-            out, new = change(stacked, out, new)
+            out, new = change(before, out, new)
             return out, new, rows
 
         faulty.returns_tokens = True
@@ -77,6 +84,39 @@ def _token_altered(session):
 def test_fault_turns_correct_false(cell, fault):
     result = tiny.run_tiny(cell, session_hook=fault)
     assert not result["correct"], result["check"]
+
+
+def _donating(session):
+    """Every wave deletes its input state once it has run, as a wave that
+    donates that state leaves it."""
+    build = session._wave_for
+
+    def wave_for(fn, sampled=False):
+        wave = build(fn, sampled)
+
+        def donated(stacked, tokens, rows):
+            result = wave(stacked, tokens, rows)
+            for leaf in jax.tree.leaves(stacked):
+                leaf.delete()
+            return result
+
+        donated.returns_tokens = True
+        return donated
+
+    session._wave_for = wave_for
+
+
+def test_state_unchanged_fault_survives_a_donated_wave():
+    """The fault returns its own copy of the input, not the deleted input:
+    the run goes on to its end and reads not correct."""
+
+    def hook(session):
+        _donating(session)
+        _state_unchanged(session)
+
+    result = tiny.run_tiny("yi6b-chat-short", session_hook=hook)
+    assert not result["correct"], result["check"]
+    assert result["info"]["checked_tokens"] > 20
 
 
 def test_fault_hook_reaches_the_wave():

@@ -5,7 +5,7 @@ import json
 
 import pytest
 
-from bench import generator, run
+from bench import families, generator, run
 
 BENCH = json.loads((run.ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -28,6 +28,28 @@ def test_config_files_state_their_cut(entry):
         k for k in sizes.get("departures", {}) if k in sizes}
     assert sorted(changed) == sorted(entry["reduced"])
     assert sizes["source"] and sizes["deployment"] and sizes["assumed"]
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_files_name_a_family_that_loads(entry):
+    sizes = families.load(run.ROOT / entry["file"])
+    family = families.of(sizes)
+    assert family.__name__ == f"bench.families.{sizes['family']}"
+    assert family.leaf_specs(sizes) and family.program_fields(sizes)
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family", "../configs"],
+                         ids=["missing", "unknown", "not_a_name"])
+def test_config_file_without_a_known_family_stops(tmp_path, family):
+    """No silent default: the run stops and names the file."""
+    sizes = json.loads((run.ROOT / BENCH["configs"][0]["file"]).read_text())
+    sizes.pop("family")
+    if family is not None:
+        sizes["family"] = family
+    path = tmp_path / "odd.json"
+    path.write_text(json.dumps(sizes))
+    with pytest.raises(SystemExit, match="odd.json"):
+        families.load(path)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"],

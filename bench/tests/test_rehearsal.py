@@ -1,10 +1,11 @@
 """Compile the cells' programs for a described TPU v5e (no chip needed).
 
-The sectored attention kernel at both configurations' widths (chatglm3's
+The sectored attention kernel at every configuration's widths (chatglm3's
 2 KV heads of 16 queries are shapes the repository's own rehearsal never
 compiled), and each cell's decode wave (the one its policy runs) and
 longest prefill at its full cut depth, batch and context, with
-``memory_analysis()`` against the chip's memory.
+``memory_analysis()`` against the chip's memory. Cells and configurations
+are those of ``BENCHMARK.json``.
 Nothing runs, so this says nothing about results or speed.
 
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests/test_rehearsal.py -s
@@ -13,14 +14,13 @@ The topology is described inside a module fixture, never at import.
 """
 
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from bench import run, weights
+from bench import families, run, weights
 from repro.kernels import backend as kbackend
 from repro.kernels import ops
 from repro.runtime import sectored_decode
@@ -28,7 +28,9 @@ from repro.sample import SamplerRows
 from repro.serve.backend import fused_wave_program
 
 HBM_BYTES = 16 * 2**30
-CELLS = ["yi6b-doc8k-dense", "chatglm3-doc8k-dense", "yi6b-chat-short"]
+BENCH = json.loads((run.ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in BENCH["workloads"]]
+CONFIGS = [c["name"] for c in BENCH["configs"]]
 
 
 @pytest.fixture(scope="module")
@@ -66,7 +68,7 @@ def _report(name: str, mem) -> None:
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("config", ["yi-6b-l8", "chatglm3-6b-l7"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_kernel_compiles_at_config_widths(one_chip, config, quantized):
     """The kernel at a doc context's budget (9 + 1 probe of 80 pages) for
     16 slots, as the sectored doc cells would run it."""
@@ -129,6 +131,15 @@ def test_cell_programs_fit_one_chip(one_chip, cell, monkeypatch):
     assert _total(prefill.memory_analysis()) < HBM_BYTES
 
 
-def test_rehearsal_names_every_cell():
-    bench = json.loads((pathlib.Path(run.ROOT) / "BENCHMARK.json").read_text())
-    assert sorted(CELLS) == sorted(w["name"] for w in bench["workloads"])
+@pytest.mark.parametrize("config", CONFIGS)
+def test_every_config_names_a_family(config):
+    """The family that the configuration names imports and has every name
+    of the contract (``bench/families/__init__.py``)."""
+    sizes = families.load(run.BENCH / "configs" / f"{config}.json")
+    family = families.of(sizes)
+    for name in families.CONTRACT:
+        assert hasattr(family, name), name
+    assert all(callable(getattr(family, n)) for n in families.CONTRACT
+               if n != "KEYS")
+    assert "dtype" in family.KEYS
+    assert set(family.KEYS) <= set(sizes)

@@ -4,11 +4,9 @@ widths and lengths. For the CPU tests only; no chip number comes from it."""
 
 import copy
 
-from bench import run
+from bench import families, run
 
 SEED = 2**31 + 12345
-TINY = dict(n_layers=2, d_model=128, n_heads=4, head_dim=32, d_ff=256,
-            vocab=512)
 # readings of sound runs at this size are under 0.01; a wrong token, a
 # stale state or the float8 control reads over 0.05
 LIMITS = {"gap": 0.03}
@@ -16,8 +14,7 @@ LIMITS = {"gap": 0.03}
 
 def spec(name: str, doc: int = 256):
     s = run.load_cell(name)
-    s.sizes = dict(s.sizes, **TINY,
-                   n_kv_heads=min(s.sizes["n_kv_heads"], 2))
+    s.sizes = families.of(s.sizes).tiny(s.sizes)
     t = copy.deepcopy(s.traffic)
     if "shared_prefix_tokens" in t:
         t["shared_prefix_tokens"] = doc
