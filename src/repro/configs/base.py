@@ -24,6 +24,48 @@ class MoEConfig:
     top_k: int
     d_expert: int  # per-expert FFN width
     n_shared_experts: int = 0
+    # the experts this chip holds, ``[held_first, held_first + held_count)``
+    # (0 = all): a held layer (``moe.held_moe``) routes over every expert,
+    # is dropless, and computes its own experts' part plus the shared
+    # experts
+    held_first: int = 0
+    held_count: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held_count or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come from
+    one compressed latent of ``kv_rank`` per token, plus a ``qk_rope_dim``
+    RoPE key shared by all heads; the cache holds both (``cache_width``)."""
+
+    kv_rank: int
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_rank + self.qk_rope_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling (arXiv:2309.00071), as DeepSeek-V2 configures it."""
+
+    factor: float
+    original_max_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +97,12 @@ class ModelConfig:
     blocked_attention: bool = False
     # §Perf: sectored decode shares page selection across kv heads
     sector_share_heads: bool = False
+    # latent attention (replaces the q/k/v projections; n_kv_heads is 1,
+    # the one latent group the cache and the sector table hold)
+    mla: MLAConfig | None = None
+    yarn: YarnConfig | None = None
+    # leading layers with a dense SwiGLU of width d_ff before the MoE ones
+    first_dense_layers: int = 0
 
     @property
     def head_dim_(self) -> int:
@@ -77,15 +125,23 @@ class ModelConfig:
     def param_count(self) -> int:
         """Approximate parameter count (for 6ND model-FLOPs accounting)."""
         emb = self.vocab * self.d_model * (1 if self.tie_embeddings else 2)
-        return emb + sum(self._layer_params(k) for k in self.layer_kinds)
+        return emb + sum(self._layer_params(k, i < self.first_dense_layers)
+                         for i, k in enumerate(self.layer_kinds))
 
-    def _layer_params(self, kind: str) -> int:
+    def _layer_params(self, kind: str, dense: bool = False) -> int:
         d = self.d_model
         hd = self.head_dim_
         n = 0
         if kind == "attn":
-            n += d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
-            if self.moe:
+            if self.mla:
+                m, h = self.mla, self.n_heads
+                n += (d * h * m.qk_dim + d * m.cache_width + m.kv_rank
+                      + m.kv_rank * h * (m.qk_nope_dim + m.v_head_dim)
+                      + h * m.v_head_dim * d)
+            else:
+                n += (d * hd * (self.n_heads + 2 * self.n_kv_heads)
+                      + self.n_heads * hd * d)
+            if self.moe and not dense:
                 e = self.moe
                 n += e.n_experts * 3 * d * e.d_expert + d * e.n_experts
                 n += e.n_shared_experts * 3 * d * e.d_expert
@@ -102,8 +158,9 @@ class ModelConfig:
         if not self.moe:
             return self.param_count()
         d, e = self.d_model, self.moe
-        dense = self.param_count() - self.n_layers * e.n_experts * 3 * d * e.d_expert
-        active = self.n_layers * (e.top_k + e.n_shared_experts) * 3 * d * e.d_expert
+        n_moe = self.n_layers - self.first_dense_layers
+        dense = self.param_count() - n_moe * e.n_experts * 3 * d * e.d_expert
+        active = n_moe * (e.top_k + e.n_shared_experts) * 3 * d * e.d_expert
         return dense + active
 
     def reduced(self, **overrides) -> "ModelConfig":
@@ -122,9 +179,15 @@ class ModelConfig:
         if self.layer_pattern:
             kw["n_layers"] = len(self.layer_pattern)
         if self.moe:
-            kw["moe"] = MoEConfig(n_experts=4, top_k=2,
-                                  d_expert=64,
-                                  n_shared_experts=self.moe.n_shared_experts)
+            kw["moe"] = dataclasses.replace(self.moe, n_experts=4, top_k=2,
+                                            d_expert=64, held_first=0,
+                                            held_count=0)
+        if self.mla:
+            kw.update(n_kv_heads=1, head_dim=24,
+                      mla=MLAConfig(kv_rank=32, qk_nope_dim=16,
+                                    qk_rope_dim=8, v_head_dim=16))
+        if self.first_dense_layers:
+            kw["n_layers"] = self.first_dense_layers + 1
         kw.update(overrides)
         return dataclasses.replace(self, **kw)
 

@@ -157,6 +157,26 @@ def init_cache(cfg, batch, seq_len, dtype=jnp.bfloat16):
 jax.tree_util.register_dataclass(KVCache, ["k", "v", "length"], [])
 
 
+def append_row(buf, layer, length, row):
+    """``buf`` (L, B, Spad, ...) with ``row`` (B, 1, ...) written at
+    ``[layer, b, length[b]]`` (a K or V cache, or a latent cache): one row
+    per sequence, written in place into the layer loop's carry. A masked
+    select over every row gives bitwise the same buffer but reads and
+    writes the whole cache; ``mode="drop"`` writes nothing for a
+    ``length`` past the buffer, as that select did.
+
+    One append serves one device and a mesh alike. With the page axis
+    sharded (``MeshBackend``'s wave, ``sharding.wave_state_shardings``)
+    the SPMD partitioner offsets the scatter's index by each shard's
+    first row and drops what falls outside it, so the write stays on the
+    shard that owns the row and no collective moves the cache: no select
+    is needed to keep it local (``tests/test_tpu_compile.py`` compiles
+    the sharded wave)."""
+    b = jnp.arange(row.shape[0])
+    return buf.at[layer, b, length].set(row[:, 0].astype(buf.dtype),
+                                        mode="drop")
+
+
 def decode_attend(params, cfg, x, cache: KVCache, window: int = 0):
     """One new token per sequence against the cache.
 

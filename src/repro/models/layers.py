@@ -7,8 +7,11 @@ NamedSharding-annotated inputs on a 512-device mesh.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rms_norm(x, scale, eps: float = 1e-5):
@@ -23,11 +26,48 @@ def init_rms(d, dtype):
 
 # --- rotary position embeddings ----------------------------------------------
 
-def _rope_angles(positions, dim, base=10000.0):
+def _rope_angles(positions, dim, base=10000.0, yarn=None):
     """positions (...,) -> cos/sin of shape (..., dim//2)."""
-    inv = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if yarn is None:
+        inv = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+        gain = 1.0
+    else:
+        inv = jnp.asarray(yarn_inv_freq(dim, yarn, base), jnp.float32)
+        gain = (yarn_mscale(yarn.factor, yarn.mscale)
+                / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions[..., None].astype(jnp.float32) * inv
-    return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * gain, jnp.sin(ang) * gain
+
+
+# --- YaRN (arXiv:2309.00071, as DeepSeek-V2 runs it) ---------------------------
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """The attention-temperature term ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_bounds(dim: int, yarn, base: float = 10000.0) -> tuple[int, int]:
+    """``(low, high)``: the rotary pairs below ``low`` keep their frequency,
+    those from ``high`` on are interpolated by the whole factor. A pair's
+    bound is where its wavelength fits ``beta`` turns into the original
+    context: ``d(beta) = dim ln(L / (2 pi beta)) / (2 ln base)``."""
+    def d(beta):
+        return (dim * math.log(yarn.original_max_positions
+                               / (beta * 2 * math.pi))
+                / (2 * math.log(base)))
+    return (max(math.floor(d(yarn.beta_fast)), 0),
+            min(math.ceil(d(yarn.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, yarn, base: float = 10000.0) -> np.ndarray:
+    """Frequencies of the ``dim // 2`` rotary pairs:
+    ``theta_i (1 - r_i) + (theta_i / factor) r_i`` with the ramp
+    ``r_i = clip((i - low) / (high - low), 0, 1)``."""
+    theta = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_bounds(dim, yarn, base)
+    span = (high - low) or 0.001
+    r = np.clip((np.arange(dim // 2) - low) / span, 0.0, 1.0)
+    return theta * (1 - r) + theta / yarn.factor * r
 
 
 def _apply_rot(x, cos, sin):
@@ -37,11 +77,13 @@ def _apply_rot(x, cos, sin):
     return out
 
 
-def apply_rope(x, positions, kind: str = "standard", base=10000.0):
+def apply_rope(x, positions, kind: str = "standard", base=10000.0,
+               yarn=None):
     """x: (B, S, H, hd); positions: (B, S) int32.
 
     kind:
-      standard — full-dim rotation (llama-family).
+      standard — full-dim rotation (llama-family); with ``yarn`` (a
+                 ``YarnConfig``) at YaRN's frequencies.
       rope2d   — ChatGLM 2-D RoPE: rotate only the first half of head_dim.
       mrope    — Qwen2-VL M-RoPE: head_dim split into 3 sections rotated by
                  (temporal, height, width) position streams; for the text-only
@@ -51,7 +93,7 @@ def apply_rope(x, positions, kind: str = "standard", base=10000.0):
     if kind == "none":
         return x
     if kind == "standard":
-        cos, sin = _rope_angles(positions, hd, base)
+        cos, sin = _rope_angles(positions, hd, base, yarn)
         return _apply_rot(x, cos[..., None, :], sin[..., None, :]).astype(x.dtype)
     if kind == "rope2d":
         half = hd // 2

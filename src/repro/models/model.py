@@ -1,8 +1,9 @@
-"""Composable LM stack: embeddings -> blocks -> head, for all 10 assigned
-architectures, with three entry points:
+"""Composable LM stack: embeddings -> blocks -> head, for every
+architecture of the registry, with three entry points:
 
 * ``forward``  — training / prefill forward pass (scan over uniform layers,
-  unrolled for hybrid patterns).
+  unrolled for hybrid patterns; latent-attention models scan their leading
+  dense layers, then their MoE layers, ``models/mla.py``).
 * ``prefill``  — forward + decode-state construction (KV caches / recurrent
   states), returns logits for the last position.
 * ``decode_step`` — one-token decode against the decode state.
@@ -19,7 +20,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.models import attention, griffin, layers, moe, rwkv
+from repro.models import attention, griffin, layers, mla, moe, rwkv
 
 
 # --- parameter construction ----------------------------------------------------
@@ -60,6 +61,30 @@ _LAYER_INIT = {"attn": _init_attn_layer, "rwkv": _init_rwkv_layer,
                "rec": _init_rec_layer}
 
 
+def _init_mla_layer(key, cfg, dtype, dense: bool):
+    k1, k2 = jax.random.split(key)
+    p = dict(norm1=layers.init_rms(cfg.d_model, dtype),
+             norm2=layers.init_rms(cfg.d_model, dtype),
+             attn=mla.init_mla(k1, cfg, dtype))
+    if dense:
+        p["mlp"] = layers.init_swiglu(k2, cfg.d_model, cfg.d_ff, dtype)
+    else:
+        p["moe"] = moe.init_moe(k2, cfg, dtype)
+    return p
+
+
+def _init_mla_stack(key, cfg, dtype) -> dict:
+    """Latent-attention models: the leading dense layers stacked under
+    ``dense``, the MoE layers stacked under ``layers``."""
+    Ld = cfg.first_dense_layers
+    keys = jax.random.split(key, cfg.n_layers)
+    return dict(
+        dense=jax.vmap(lambda k: _init_mla_layer(k, cfg, dtype, True))(
+            keys[:Ld]),
+        layers=jax.vmap(lambda k: _init_mla_layer(k, cfg, dtype, False))(
+            keys[Ld:]))
+
+
 def init_params(cfg, key) -> dict:
     dtype = jnp.dtype(cfg.dtype)
     kinds = cfg.layer_kinds
@@ -70,6 +95,8 @@ def init_params(cfg, key) -> dict:
     params["final_norm"] = layers.init_rms(cfg.d_model, dtype)
     if cfg.n_layers == 0:  # dry-run probe: base graph without the stack
         params["layers"] = {}
+    elif cfg.mla:
+        params.update(_init_mla_stack(k_layers, cfg, dtype))
     elif cfg.uniform_layers or cfg.attn_free:
         # stacked params, applied via lax.scan over the leading L axis
         lkeys = jax.random.split(k_layers, cfg.n_layers)
@@ -102,6 +129,16 @@ def _attn_block(lp, cfg, x, positions, window=0):
     return x
 
 
+def _mla_block(lp, cfg, x, positions):
+    h = layers.rms_norm(x, lp["norm1"], cfg.norm_eps)
+    x = x + mla.attend(lp["attn"], cfg, h, positions)
+    h = layers.rms_norm(x, lp["norm2"], cfg.norm_eps)
+    if "mlp" in lp:
+        return x + layers.swiglu(lp["mlp"], h)
+    y, _ = moe.held_moe(lp["moe"], cfg, h.reshape(-1, h.shape[-1]))
+    return x + y.reshape(x.shape)
+
+
 def _rec_block(lp, cfg, x, h0):
     h = layers.rms_norm(x, lp["norm1"], cfg.norm_eps)
     y, h_last = griffin.rglru(lp["rec"], h, h0)
@@ -127,6 +164,13 @@ def forward(params, cfg, tokens=None, *, embeds=None, positions=None,
     kinds = cfg.layer_kinds
     if cfg.n_layers == 0:
         pass
+    elif cfg.mla:
+        def body(x, lp):
+            return _mla_block(lp, cfg, x, positions), None
+        if remat:
+            body = jax.checkpoint(body)
+        for stack in ("dense", "layers"):
+            x, _ = jax.lax.scan(body, x, params[stack])
     elif cfg.attn_free:
         def body(x, lp):
             state = rwkv.init_state(cfg, B)
@@ -200,6 +244,10 @@ def init_decode_state(cfg, batch, seq_len, dtype=jnp.bfloat16) -> DecodeState:
     if cfg.n_layers == 0:
         return DecodeState(kv=None, rec=None,
                            position=jnp.zeros((batch,), jnp.int32))
+    if cfg.mla:
+        return DecodeState(
+            kv=mla.init_cache(cfg, batch, _pad_seq(seq_len), dtype),
+            rec=None, position=jnp.zeros((batch,), jnp.int32))
     if cfg.attn_free:
         rec = jax.tree.map(
             lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape),
@@ -238,6 +286,15 @@ def decode_step(params, cfg, state: DecodeState, token):
     B = x.shape[0]
     if cfg.n_layers == 0:
         new = state
+    elif cfg.mla:
+        def attend(p, q, rows, length, table_l):
+            valid = jnp.arange(rows.shape[1])[None] <= length[:, None]
+            out, _ = mla.latent_attend(p, cfg, x.dtype, q, rows, valid)
+            return out, None
+        x, kv, _, _ = mla.decode_layers(params, cfg, x, state.kv, None,
+                                        attend)
+        kv = mla.LatentCache(rows=kv.rows, length=kv.length + 1)
+        new = DecodeState(kv=kv, rec=None, position=state.position + 1)
     elif cfg.attn_free:
         def body(x, scans):
             lp, st = scans
@@ -298,6 +355,16 @@ def prefill(params, cfg, tokens):
     (one extra pass over the prompt projections, cheap relative to attention).
     """
     B, S = tokens.shape
+    if cfg.mla:
+        # the decode step scanned over the prompt: the cache rows are the
+        # ones decode writes
+        def body(carry, tok):
+            _, state = carry
+            return decode_step(params, cfg, state, tok[:, None]), None
+        init = decode_step(params, cfg, init_decode_state(cfg, B, S),
+                           tokens[:, :1])
+        (logits, state), _ = jax.lax.scan(body, init, tokens[:, 1:].T)
+        return logits, state
     hidden = forward(params, cfg, tokens)
     logits = logits_fn(params, cfg, hidden)[:, -1, :]
     state = init_decode_state(cfg, B, S)

@@ -7,6 +7,12 @@ Under pjit with the expert axis sharded over "model", XLA SPMD lowers the
 gathers into the expected all-to-all exchanges. Capacity overflow drops
 tokens (standard capacity-factor semantics); dropped tokens fall back to the
 residual path.
+
+``held_moe`` is the dropless layer of a chip that holds a share of the
+experts (``MoEConfig.held_first`` / ``held_count``): it routes every token
+over all experts, computes the held experts' weighted part and the shared
+experts, and has no capacity, so a token's output never depends on what
+else is in the batch.
 """
 
 from __future__ import annotations
@@ -21,11 +27,12 @@ def init_moe(key, cfg, dtype):
     d, e = cfg.d_model, cfg.moe
     ks = jax.random.split(key, 5)
     s = d ** -0.5
+    n = e.n_held
     p = dict(
         router=jax.random.normal(ks[0], (d, e.n_experts), jnp.float32) * s,
-        w_gate=jax.random.normal(ks[1], (e.n_experts, d, e.d_expert), dtype) * s,
-        w_up=jax.random.normal(ks[2], (e.n_experts, d, e.d_expert), dtype) * s,
-        w_down=jax.random.normal(ks[3], (e.n_experts, e.d_expert, d), dtype)
+        w_gate=jax.random.normal(ks[1], (n, d, e.d_expert), dtype) * s,
+        w_up=jax.random.normal(ks[2], (n, d, e.d_expert), dtype) * s,
+        w_down=jax.random.normal(ks[3], (n, e.d_expert, d), dtype)
         * (e.d_expert ** -0.5),
     )
     if e.n_shared_experts:
@@ -88,3 +95,38 @@ def moe_ffn(params, cfg, x):
 
         out = out + swiglu(params["shared"], xt)
     return out.reshape(B, S, D)
+
+
+def route(router, cfg, x):
+    """Router weights and experts of each token, in float32: x (T, D) ->
+    (gate (T, k), idx (T, k)). DeepSeek-V2's greedy routing: softmax over
+    every expert, then the top-k scores as they are (not renormalized,
+    scale 1)."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router.astype(jnp.float32))
+    return jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.moe.top_k)
+
+
+def held_moe(params, cfg, x):
+    """Dropless held-expert layer: x (T, D) -> (y (T, D), counts (T, held)
+    int32, how many of each token's top-k picks fall on each held expert).
+
+    ``y = sum over the token's top-k experts e that this chip holds of
+    gate_e SwiGLU_e(x) + SwiGLU_shared(x)``. Every held expert is computed
+    for every token and weighted by its gate, 0 where the token did not
+    pick it: a decode wave reads each held expert's weights once, whatever
+    the routing."""
+    e = cfg.moe
+    gate, idx = route(params["router"], cfg, x)
+    picks = (idx - e.held_first)[..., None] == jnp.arange(e.n_held)
+    w = jnp.sum(jnp.where(picks, gate[..., None], 0.0), axis=1)  # (T, held)
+    g = jnp.einsum("td,edf->tef", x, params["w_gate"])
+    u = jnp.einsum("td,edf->tef", x, params["w_up"])
+    h = jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+    y = jnp.einsum("tef,efd->td", (h * w[..., None]).astype(x.dtype),
+                   params["w_down"], preferred_element_type=jnp.float32)
+    if e.n_shared_experts:
+        from repro.models.layers import swiglu
+
+        y = y + swiglu(params["shared"], x).astype(jnp.float32)
+    return y.astype(x.dtype), jnp.sum(picks, axis=1, dtype=jnp.int32)

@@ -42,7 +42,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels import ops, quantized_kv
-from repro.models import attention, layers, model, moe
+from repro.models import attention, layers, mla, model, moe
+from repro.models.attention import append_row
 from repro.parallel import sharding
 from repro.runtime import sector_predictor
 from repro.serve.backend import ServingBackend
@@ -69,12 +70,18 @@ def topk_for(seq_len: int, frac: float = TOPK_FRAC,
 
 @dataclasses.dataclass
 class SectoredState:
-    kv: Any  # stacked attention.KVCache (L, B, Spad, Hkv, hd)
+    # stacked attention.KVCache (L, B, Spad, Hkv, hd), or for latent
+    # attention an mla.LatentCache (L, B, Spad, mla.row_width(cfg))
+    kv: Any
     table: jax.Array  # (L, B, Hkv, P) sector-history table
     position: jax.Array  # (B,)
+    # held-expert layers: each held expert's token count of the last step,
+    # (L_moe, B, held experts); None without them
+    experts: Any = None
 
 
-jax.tree_util.register_dataclass(SectoredState, ["kv", "table", "position"], [])
+jax.tree_util.register_dataclass(
+    SectoredState, ["kv", "table", "position", "experts"], [])
 
 
 def init_state(cfg, batch, seq_len, dtype=jnp.bfloat16) -> SectoredState:
@@ -85,33 +92,20 @@ def init_state(cfg, batch, seq_len, dtype=jnp.bfloat16) -> SectoredState:
     # divides every mesh-axis product (<= 512 = 4*128)
     pages = ((n_pages(seq_len + 8) + 7) // 8) * 8
     pad = pages * PAGE_SIZE
+    position = jnp.zeros((batch,), jnp.int32)
+    if cfg.mla:
+        moe_layers = cfg.n_layers - cfg.first_dense_layers
+        return SectoredState(
+            kv=mla.init_cache(cfg, batch, pad, dtype),
+            table=sector_predictor.init_table(cfg.n_layers, batch, 1, pages),
+            position=position,
+            experts=jnp.zeros((moe_layers, batch, cfg.moe.n_held), jnp.int32))
     cache = attention.init_cache(cfg, batch, pad, dtype)
     kv = jax.tree.map(
         lambda x: jnp.broadcast_to(x, (cfg.n_layers,) + x.shape), cache)
     table = sector_predictor.init_table(cfg.n_layers, batch, cfg.n_kv_heads,
                                         pages)
-    return SectoredState(kv=kv, table=table,
-                         position=jnp.zeros((batch,), jnp.int32))
-
-
-def append_row(buf, layer, length, row):
-    """``buf`` (L, B, Spad, Hkv, hd) with ``row`` (B, 1, Hkv, hd) written
-    at ``[layer, b, length[b]]``: one row per sequence, written in place
-    into the layer loop's carry. A masked select over every row
-    gives bitwise the same buffer but reads and writes the whole cache;
-    ``mode="drop"`` writes nothing for a ``length`` past the buffer, as
-    that select did.
-
-    One append serves one device and a mesh alike. With the page axis
-    sharded (``MeshBackend``'s wave, ``sharding.wave_state_shardings``)
-    the SPMD partitioner offsets the scatter's index by each shard's
-    first row and drops what falls outside it, so the write stays on the
-    shard that owns the row and no collective moves the cache: no select
-    is needed to keep it local (``tests/test_tpu_compile.py`` compiles
-    the sharded wave)."""
-    b = jnp.arange(row.shape[0])
-    return buf.at[layer, b, length].set(row[:, 0].astype(buf.dtype),
-                                        mode="drop")
+    return SectoredState(kv=kv, table=table, position=position)
 
 
 def sectored_attend(attn_params, cfg, x, q, k, v, length, table_l,
@@ -272,6 +266,64 @@ def _attend_fused(attn_params, cfg, x, q, k, v, length, table_l, page_idx,
     return out, sector_predictor.update(table_l, pages, mass)
 
 
+def latent_sectored_attend(attn_params, cfg, x_dtype, q, rows, length,
+                           table_l, k_pages: int, probe: bool = False):
+    """One-token latent attention (``mla.latent_attend``) over the layer's
+    latent cache ``rows`` (B, Spad, W), this token's row already written
+    at ``length`` (B,), the pre-append lengths. The cache is one group, so
+    ``table_l`` (B, 1, P) holds each page's mass summed over the heads.
+
+    With every page selected and no probe (exact mode) the rows are read in
+    place: no page is gathered and no slice of the cache is copied. Below
+    that, the predictor's top-k pages (plus the probe) are gathered first,
+    as ``sectored_attend``'s dispatch path does. Returns (out, new
+    table_l)."""
+    B, S, W = rows.shape
+    P = S // PAGE_SIZE
+    if k_pages >= P and not probe:
+        valid = jnp.arange(S)[None, :] <= length[:, None]
+        out, e = mla.latent_attend(attn_params, cfg, x_dtype, q, rows, valid)
+        pages = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, 1, P))
+        K = P
+    else:
+        probe_page = (sector_predictor.probe_page_for(length, PAGE_SIZE)
+                      if probe else None)
+        pages = sector_predictor.predict_topk(
+            table_l, length, PAGE_SIZE, k_pages + int(probe),
+            probe_page=probe_page)  # (B, 1, K)
+        K = pages.shape[-1]
+        sel = jnp.take_along_axis(
+            rows.reshape(B, P, PAGE_SIZE, W),
+            pages[:, 0, :, None, None], axis=1)  # (B, K, page, W)
+        tok_pos = pages[:, 0, :, None] * PAGE_SIZE + jnp.arange(PAGE_SIZE)
+        valid = (tok_pos <= length[:, None, None]).reshape(B, K * PAGE_SIZE)
+        out, e = mla.latent_attend(attn_params, cfg, x_dtype, q,
+                                   sel.reshape(B, K * PAGE_SIZE, W), valid)
+    mass = jnp.sum(e.reshape(B, -1, K, PAGE_SIZE), axis=(1, 3)) / (
+        jnp.maximum(jnp.sum(e, axis=(1, 2))[:, None], 1e-30))  # (B, K)
+    return out, sector_predictor.update(table_l, pages, mass[:, None])
+
+
+def _latent_decode_step(params, cfg, state: SectoredState, token,
+                        k_pages: int, probe: bool):
+    """:func:`sectored_decode_step` for latent attention: the leading
+    dense layers, then the held-expert ones (``mla.decode_layers``)."""
+    x = layers.embed(params, token)
+
+    def attend(p, q, rows, length, table_l):
+        return latent_sectored_attend(p, cfg, x.dtype, q, rows, length,
+                                      table_l, k_pages, probe)
+
+    x, kv, table, experts = mla.decode_layers(params, cfg, x, state.kv,
+                                              state.table, attend)
+    hidden = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = model.logits_fn(params, cfg, hidden)[:, 0, :]
+    kv = mla.LatentCache(rows=kv.rows, length=kv.length + 1)
+    return logits, SectoredState(kv=kv, table=table,
+                                 position=state.position + 1,
+                                 experts=experts)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "k_pages", "probe", "kernel"))
 def sectored_decode_step(params, cfg, state: SectoredState, token,
@@ -290,7 +342,10 @@ def sectored_decode_step(params, cfg, state: SectoredState, token,
     genuinely narrow page budgets. ``kernel`` likewise forwards (see
     :func:`sectored_attend`): ``"fused"`` runs the single-Pallas-kernel
     attend (bitwise with ``"dispatch"``), ``"fused_q8"`` adds per-sector
-    int8 KV (tolerance-gated)."""
+    int8 KV (tolerance-gated). Latent attention (``cfg.mla``) runs
+    :func:`_latent_decode_step`, dispatch only."""
+    if cfg.mla and cfg.n_layers:
+        return _latent_decode_step(params, cfg, state, token, k_pages, probe)
     x = layers.embed(params, token)
     if cfg.n_layers == 0:  # dry-run probe base
         hidden = layers.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -371,8 +426,7 @@ def or_merge_demands(stacked_state: SectoredState, group_ids) -> SectoredState:
     if stacked_state.kv is None:  # dry-run probe base: nothing to pool
         return stacked_state
     pooled = sector_predictor.pool_demands(stacked_state.table, group_ids)
-    return SectoredState(kv=stacked_state.kv, table=pooled,
-                         position=stacked_state.position)
+    return dataclasses.replace(stacked_state, table=pooled)
 
 
 def unique_fetches(pages, group_ids) -> int:
@@ -415,6 +469,12 @@ class SectoredKVBackend(ServingBackend):
         if kernel not in self.KERNELS:
             raise ValueError(f"kernel must be one of {self.KERNELS}; "
                              f"got {kernel!r}")
+        if cfg.mla and kernel != "dispatch":
+            raise ValueError(
+                f"kernel {kernel!r}: the fused kernels attend grouped-query "
+                f"K/V pages and have no latent-attention form; "
+                f"{cfg.name!r} uses latent attention, serve it with kernel "
+                f"'dispatch'")
         self.cfg = cfg
         self.params = params
         self.seq_len = seq_len
@@ -537,10 +597,9 @@ class SectoredKVBackend(ServingBackend):
         """
         n = int(n_tokens)
         kv = state.kv
-        new_kv = attention.KVCache(k=kv.k, v=kv.v,
-                                   length=jnp.full_like(kv.length, n))
-        return SectoredState(kv=new_kv, table=state.table,
-                             position=jnp.full_like(state.position, n))
+        new_kv = dataclasses.replace(kv, length=jnp.full_like(kv.length, n))
+        return dataclasses.replace(state, kv=new_kv,
+                                   position=jnp.full_like(state.position, n))
 
     def suffix_prefill(self, state: SectoredState, tokens):
         """Resume exact-mode prefill from a seeded state (warm admission:

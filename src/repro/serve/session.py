@@ -385,7 +385,8 @@ class ServeSession:
         return dict(decode_steps=0, sectored_steps=0, completed=0, waves=0,
                     sectored_waves=0, merged_slots=0, overlapped_prefills=0,
                     prefill_calls=0, prefill_tokens=0, preemptions=0,
-                    eos_stops=0)
+                    eos_stops=0, context_tokens=0, expert_tokens=0,
+                    experts_touched=0)
 
     def reset_stats(self) -> None:
         self.stats = self._zero_stats()
@@ -1160,7 +1161,10 @@ class ServeSession:
             finally:
                 self.wave_in_flight = False
             with TraceAnnotation("session.wave_sync"):
-                next_tok, logps = self._collect_wave(wave, out, sampled)
+                next_tok, logps, experts = self._collect_wave(
+                    wave, out, sampled)
+            if experts is not None:
+                self._count_experts(active, experts)
         else:
             with TraceAnnotation("session.wave_dispatch", active=len(active)):
                 next_tok, logps = self._run_looped(active, fn)
@@ -1191,25 +1195,31 @@ class ServeSession:
         return produced
 
     def _collect_wave(self, wave, out, sampled: bool
-                      ) -> tuple[np.ndarray, np.ndarray]:
+                      ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
         """Block on a dispatched wave: its tokens and their logprobs on
-        the host, (max_batch,) each."""
+        the host, (max_batch,) each, and the held-expert counts its state
+        carries (``SectoredState.experts``; None for a model without
+        them), all in one read."""
+        experts = getattr(self.batched, "experts", None)
         if getattr(wave, "returns_tokens", False):
             # fused pipeline (the default): tokens were selected
             # on-device — per-slot first-max argmax or the sampling
             # kernel, bit-identical to the reference paths below; the
             # per-token logprob rode out in the sampler rows
-            next_tok = np.asarray(out).reshape(self.max_batch, -1)[:, 0]
+            out, logp, experts = jax.device_get(
+                (out, self._sampler_rows.logp, experts))
+            next_tok = out.reshape(self.max_batch, -1)[:, 0]
             self._token_feedback_np = next_tok
-            return next_tok, np.asarray(self._sampler_rows.logp)
+            return next_tok, logp, experts
         if sampled:
             # pre-fused reference (fuse_wave=False): one extra jitted
             # dispatch applies the SAME per-slot selection kernel to
             # the wave's logits, advancing the RNG counters exactly
             # like the fused executable does
             toks, self._sampler_rows = select_tokens(out, self._sampler_rows)
-            next_tok = np.asarray(toks).reshape(self.max_batch, -1)[:, 0]
-            return next_tok, np.asarray(self._sampler_rows.logp)
+            toks, logp, experts = jax.device_get(
+                (toks, self._sampler_rows.logp, experts))
+            return toks.reshape(self.max_batch, -1)[:, 0], logp, experts
         # greedy pre-fused wave: the literal pre-fusion baseline (host
         # argmax over the pulled logits) — the honest denominator of the
         # benchmark's fused_speedup. Sampler counters need no advance
@@ -1217,8 +1227,20 @@ class ServeSession:
         # stochastic request gets its counter scattered fresh at install
         next_tok = np.asarray(jnp.argmax(out, axis=-1)).reshape(
             self.max_batch, -1)[:, 0]
-        return next_tok, np.asarray(token_logprobs(
-            out, jnp.asarray(next_tok, jnp.int32)))
+        logp, experts = jax.device_get((token_logprobs(
+            out, jnp.asarray(next_tok, jnp.int32)), experts))
+        return next_tok, logp, experts
+
+    def _count_experts(self, active: list[int], experts: np.ndarray
+                       ) -> None:
+        """Fold the wave's held-expert counts (``experts``, read with its
+        tokens) into ``stats``: the tokens the active slots routed to held
+        experts (``expert_tokens``) and the (layer, held expert) pairs that
+        at least one of them used (``experts_touched``)."""
+        counts = experts[active].sum(axis=0)
+        self.stats["expert_tokens"] += int(counts.sum())
+        self.stats["experts_touched"] += int(
+            np.count_nonzero(counts.sum(axis=-2)))
 
     def _meter_wave_info(self, active: list[int], decision,
                          use_sectored: bool) -> dict:
@@ -1343,6 +1365,9 @@ class ServeSession:
             handle._logprobs.append(float(logps[s]))
             produced += 1
             self.stats["decode_steps"] += 1
+            # the cache rows this step attended: the slot's length + 1
+            self.stats["context_tokens"] += (len(handle.request.prompt)
+                                             + len(handle._tokens) - 1)
             if use_sectored:
                 self.stats["sectored_steps"] += 1
             if tok in handle._stop:

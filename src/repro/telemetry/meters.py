@@ -61,8 +61,9 @@ class KVGeometry:
     """Static KV-cache layout the meter converts counters with.
 
     ``page_kv_bytes`` is the K+V footprint of ONE page in ONE layer across
-    all kv heads — per-wave traffic scales by ``n_layers`` because every
-    layer re-fetches its own cache.
+    all kv heads (for latent attention, the page's latent rows) — per-wave
+    traffic scales by ``n_layers`` because every layer re-fetches its own
+    cache.
 
     ``kv_word_fraction`` is the bytes-per-word term of sectored decode
     fetches (``power.kv_fetch_energy``): 1.0 for the bf16 cache, 0.5 when
@@ -94,8 +95,11 @@ class KVGeometry:
         for backends with a padded page capacity (SectoredKVBackend passes
         its own) — the K+V byte formula stays in this one place.
         """
-        page_kv_bytes = (page_size * cfg.n_kv_heads * cfg.head_dim_
-                         * 2 * kv_dtype_bytes)
+        if cfg.mla:  # one latent row per token, no separate values
+            page_kv_bytes = page_size * cfg.mla.cache_width * kv_dtype_bytes
+        else:
+            page_kv_bytes = (page_size * cfg.n_kv_heads * cfg.head_dim_
+                             * 2 * kv_dtype_bytes)
         if total_pages is None:
             total_pages = max(math.ceil(seq_len / page_size), 1)
         return cls(page_size=page_size, total_pages=total_pages,

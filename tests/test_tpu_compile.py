@@ -228,6 +228,61 @@ def test_decode_wave_writes_rows_in_place(one_chip, path, monkeypatch):
         rewrites
 
 
+def _outside_fusions(hlo_text: str, shapes) -> list[str]:
+    """Instructions of compiled HLO, outside fused computations, whose
+    array shape is one of ``shapes`` (compared as sorted non-unit dims):
+    buffers a program materializes, as opposed to the operands a fusion
+    reads in place."""
+    def key(dims):
+        return tuple(sorted(d for d in dims if d != 1))
+
+    wanted = {key(s) for s in shapes}
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.-]+)", hlo_text))
+    found, computation = [], None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.-]+) \(", line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.-]+) = \w+\[([0-9,]+)\]\{[^}]*\} "
+                     r"([\w-]+)\(", line)
+        if m and computation not in fused:
+            name, dims, opcode = m.groups()
+            if key(int(d) for d in dims.split(",")) in wanted:
+                found.append(f"{name} {opcode} [{dims}]")
+    return found
+
+
+def test_latent_wave_reads_the_cache_in_place(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's exact decode wave at published widths (a 3-layer
+    cut: the dense layer and two MoE layers, so the MoE layers stay a
+    loop), the doc cells' 16 slots of 9344 tokens: no layer of the latent
+    cache is materialized (its reads are fused into the attention's dots),
+    the whole cache is copied once (the wave's input into its output) and
+    otherwise only written in place, and no layout change transposes it."""
+    monkeypatch.setattr(kbackend, "default_interpret", lambda: False)
+    base = configs.get("deepseek-v2-lite")
+    cfg = dataclasses.replace(base, n_layers=3, moe=dataclasses.replace(
+        base.moe, held_count=8))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.key(0))))
+    slots, seq_len = 16, 9344
+    backend = sectored_decode.SectoredKVBackend(cfg, params, seq_len=seq_len)
+    stacked = _wave_state(cfg, slots, seq_len)
+    jitted, bound = fused_wave_program(backend.decode_fn)
+    compiled = jitted.lower(*_on(one_chip, (
+        bound, stacked, jax.ShapeDtypeStruct((slots, 1, 1), jnp.int32),
+        jax.eval_shape(lambda: SamplerRows.init(slots))))).compile()
+    whole = stacked.kv.rows.shape
+    text = compiled.as_text()
+    assert not _outside_fusions(text, [whole[:1] + whole[2:]])
+    found = _outside_fusions(text, [whole])
+    assert all(f.split()[1] in ("parameter", "bitcast", "copy", "fusion",
+                                "get-tuple-element") for f in found), found
+    assert sum(f.split()[1] == "copy" for f in found) == 1, found
+    assert _bytes(compiled.memory_analysis()) < HBM_BYTES
+
+
 def test_rewrite_scan_catches_one_hot_append(one_chip):
     """The rewrite check is not vacuous: the append this step had — each
     layer's cache scanned in, a one-hot select writes the row, the caches
