@@ -243,10 +243,26 @@ def fused_wave_program(fn: Callable, *, sampled: bool = False
                        ) -> tuple[Callable, tuple]:
     """``(jitted, bound)``: ``jitted(bound, stacked, tokens, rows)`` is the
     ``jit(vmap)`` of :func:`fused_select_step` over the slot axis, with
-    the step's bound weights an unbatched argument."""
+    the step's bound weights an unbatched argument.
+
+    **The wave consumes its input state.** ``stacked`` is donated, so XLA
+    aliases every state leaf to the matching output leaf: the layer loop
+    writes each token's K/V (or latent) row into the input buffer, and
+    no copy of the whole cache into a fresh output is made. After the
+    call every leaf of ``stacked`` is deleted; a caller must never read
+    it again, only the returned state. Its two callers hold to this:
+    ``ServeSession`` (through :func:`make_fused_wave`) replaces its
+    stacked state with the output, and ``MeshBackend.wave_for`` keeps
+    only the output as its placement anchor. ``bound`` (the weights),
+    ``tokens`` (the previous wave's output, fed back) and ``rows`` are
+    not donated. The pre-fused reference wave (``fuse_wave=False``)
+    stays undonated, as the oracle the tests compare against, and so
+    does the warm prefill, whose input aliases the prefix cache's donor
+    buffers."""
     step, bound = split_bound(fn)
     jitted = jax.jit(jax.vmap(fused_select_step(step, sampled=sampled),
-                              in_axes=(None, 0, 0, 0)))
+                              in_axes=(None, 0, 0, 0)),
+                     donate_argnums=(1,))
     return jitted, bound
 
 
@@ -259,6 +275,10 @@ def make_fused_wave(fn: Callable, *, sampled: bool = False) -> Callable:
     ``wave_for`` builds the same per-slot step and adds placement.
     Memoization is the caller's job (``ServeSession._wave_for`` caches
     per ``(id(fn), sampled)``).
+
+    Like :func:`fused_wave_program`, the returned ``wave`` consumes its
+    input state: ``stacked`` is donated and deleted by the call, so the
+    caller keeps only the ``new_state`` it returns.
     """
     jitted, bound = fused_wave_program(fn, sampled=sampled)
 

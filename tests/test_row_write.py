@@ -10,6 +10,11 @@ positions — for the exact dispatch path and the fused kernel (interpret
 mode on the CPU), over lengths that land on the first row of a page and
 on the last row of the padded cache, for one step, a prefill scan and the
 slot-vmapped wave.
+
+The session's fused wave donates its stacked state, so the rows are
+written into the buffer it was given: that buffer is gone after the
+wave, and the streams, stops and held-expert counts still equal the
+undonated reference wave's (``fuse_wave=False``).
 """
 
 import functools
@@ -198,9 +203,11 @@ def test_wave_matches_one_hot_append(setup, path, sampled):
     _assert_bitwise(jitted(bound, stacked, tokens, rows), want)
 
 
-def _doc_requests(cfg):
+def _doc_requests(cfg, stops=None):
     """A shared document prefix, greedy and sampled questions on it, and
-    staggered arrivals so admissions fall between waves."""
+    staggered arrivals so admissions fall between waves. ``stops`` maps a
+    request id to its stop token."""
+    stops = stops or {}
     doc = (np.arange(40) * 5 % cfg.vocab).astype(np.int32)
     reqs = []
     for rid in range(6):
@@ -209,11 +216,13 @@ def _doc_requests(cfg):
         spec = (SamplerSpec(temperature=0.8, top_p=0.9, seed=40 + rid)
                 if rid % 2 else None)
         reqs.append(Request(rid, np.concatenate([doc, suffix]),
-                            max_new_tokens=5 + rid % 4, sampler=spec))
+                            max_new_tokens=5 + rid % 4, sampler=spec,
+                            stop_tokens=((stops[rid],) if rid in stops
+                                         else ())))
     return reqs
 
 
-def _serve(cfg, params, *, fuse_wave):
+def _serve(cfg, params, *, fuse_wave, stops=None):
     backend = sectored_decode.make_serving_fns(cfg, params=params,
                                                seq_len=SEQ_LEN)
     cache = PrefixCache(capacity_pages=64, page_size=16)
@@ -221,7 +230,7 @@ def _serve(cfg, params, *, fuse_wave):
                         policy=AlwaysDense(), prefix_cache=cache,
                         fuse_wave=fuse_wave)
     handles = []
-    for req in _doc_requests(cfg):
+    for req in _doc_requests(cfg, stops):
         handles.append(sess.submit(req))
         sess.step()
     sess.run_until_drained()
@@ -240,3 +249,80 @@ def test_session_streams_match_pre_fused_wave(setup):
     assert fused == reference
     assert sess.stats["waves"] > 0
     assert cache.stats["hits"] > 0  # warm admissions took part
+
+
+@pytest.mark.parametrize("fuse_wave", [True, False],
+                         ids=["fused", "reference"])
+def test_wave_consumes_its_input_state(setup, fuse_wave):
+    """The fused wave donates the session's stacked state: after a step
+    with no admission, every leaf of the state it started from is
+    deleted, and the session holds the wave's output. The pre-fused
+    reference wave donates nothing."""
+    cfg, params = setup
+    backend = sectored_decode.make_serving_fns(cfg, params=params,
+                                               seq_len=SEQ_LEN)
+    sess = ServeSession(backend, max_batch=2, policy=AlwaysDense(),
+                        fuse_wave=fuse_wave)
+    for rid in range(2):
+        sess.submit(Request(rid, np.arange(5 + rid, dtype=np.int32),
+                            max_new_tokens=6))
+    sess.step()  # admits both, then one wave
+    before = jax.tree.leaves(sess.batched)
+    assert sess.step() == 2  # a wave alone: both slots decode
+    assert all(x.is_deleted() == fuse_wave for x in before)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(sess.batched))
+
+
+def test_donated_waves_match_the_reference_with_stops(setup):
+    """Admissions during the wave's overlap, warm prefix hits, sampled
+    slots, and slots vacated on their stop token, over donated waves:
+    the streams equal the pre-fused reference's (``fuse_wave=False``)."""
+    cfg, params = setup
+    free, _, _ = _serve(cfg, params, fuse_wave=False)
+    # a greedy and a sampled request stop on a token they first emit in
+    # a wave: the stream's third token or later, not seen before it
+    stops = {}
+    for rid in (1, 2):
+        toks = free[rid][0]
+        stops[rid] = next(t for i, t in enumerate(toks)
+                          if i >= 2 and t not in toks[:i])
+    fused, sess, cache = _serve(cfg, params, fuse_wave=True, stops=stops)
+    reference, _, _ = _serve(cfg, params, fuse_wave=False, stops=stops)
+    assert fused == reference
+    for rid, stop in stops.items():
+        toks = fused[rid][0]
+        assert toks[-1] == stop and len(toks) < len(free[rid][0])
+        assert toks == free[rid][0][:len(toks)]
+    assert sess.stats["eos_stops"] == len(stops)
+    assert sess.stats["overlapped_prefills"] > 0
+    assert cache.stats["hits"] > 0
+
+
+def test_latent_session_counts_experts_after_donated_waves():
+    """DeepSeek-V2-Lite's blocks at tiny sizes: the held-expert counts the
+    session reads from the state a donated wave returns equal the
+    pre-fused reference's, and so do the streams."""
+    from bench import run, weights
+    from bench.tests import tiny
+
+    sizes = tiny.spec("dsv2lite-doc8k-dense").sizes
+    cfg = run.program_config(sizes)
+    params = weights.make(sizes, tiny.SEED)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab, size=12 + i).astype(np.int32)
+               for i in range(5)]
+
+    def serve(fuse_wave):
+        backend = sectored_decode.SectoredKVBackend(cfg, params, seq_len=384)
+        sess = ServeSession(backend, max_batch=3, policy=AlwaysDense(),
+                            fuse_wave=fuse_wave)
+        handles = [sess.submit(Request(i, p, max_new_tokens=5))
+                   for i, p in enumerate(prompts)]
+        sess.run_until_drained()
+        return sess.stats, [tuple(h.peek()) for h in handles]
+
+    (stats, streams), (ref_stats, ref_streams) = serve(True), serve(False)
+    assert streams == ref_streams
+    assert stats["expert_tokens"] > 0
+    assert stats["expert_tokens"] == ref_stats["expert_tokens"]
+    assert stats["experts_touched"] == ref_stats["experts_touched"]
